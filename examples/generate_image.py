@@ -27,6 +27,7 @@ from repro.core.qlinear import param_bytes
 from repro.engine import (SD_TURBO, TINY_SD, DiffusionEngine,
                           GenerateRequest, PreviewLatent, default_sampler,
                           init_pipeline, list_samplers, quantize_pipeline)
+from repro.launch import compile_cache
 
 
 def main():
@@ -46,6 +47,7 @@ def main():
     args = ap.parse_args()
     if args.steps < 1:
         ap.error("--steps must be >= 1")
+    compile_cache.enable()
 
     cfg = TINY_SD if args.size == "tiny" else SD_TURBO
     sampler = args.sampler or default_sampler(args.steps)

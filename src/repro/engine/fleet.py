@@ -70,6 +70,8 @@ import dataclasses
 import time
 from typing import Any, Callable, Iterator
 
+import jax
+
 from repro.distributed.fault_tolerance import (DRAINING, EVICTED,
                                                ReplicaHealth, Watchdog)
 from repro.engine import events as ev
@@ -98,7 +100,7 @@ class ReplicaSpec:
     then share its cost model / metrics registry, while each still owns
     its cache and bus — the fleet rebinds the bus before any event is
     emitted).  The legacy ``build`` closure is still honoured and wins
-    when set."""
+    when set; it places its own params."""
     name: str
     build: Callable[[], Any] | None = None
     params: Any = None
@@ -106,8 +108,10 @@ class ReplicaSpec:
     engine: str = "lm"
     config: Any = None          # engine.config.EngineConfig | None
 
-    def make(self) -> Any:
-        """Construct this replica's engine."""
+    def make(self, device: Any = None) -> Any:
+        """Construct this replica's engine, its params committed to
+        ``device`` when one is given (the engine's programs then run
+        there: jit follows committed arguments)."""
         if self.build is not None:
             return self.build()
         if self.params is None or self.model_cfg is None:
@@ -116,6 +120,8 @@ class ReplicaSpec:
                 "(params, model_cfg[, config])")
         from repro.engine.config import build_engine
         params = self.params() if callable(self.params) else self.params
+        if device is not None:
+            params = jax.device_put(params, device)
         return build_engine(self.engine, params, self.model_cfg,
                             self.config)
 
@@ -173,6 +179,7 @@ class _Replica:
     spec: ReplicaSpec
     engine: Any
     health: ReplicaHealth
+    device: Any = None        # the device this replica's params live on
     steps: int = 0            # quanta this replica has run (busy only)
     evicted: bool = False     # eviction (incl. migration) already ran
 
@@ -199,8 +206,11 @@ class FleetManager(ev.EventStreamMixin):
         self._wd_params = (watchdog_threshold, watchdog_alpha,
                            suspect_limit)
         self.replicas: list[_Replica] = []
-        for spec in specs:
-            self._spawn(spec)
+        # Replica i lives on device i (round-robin past the device
+        # count), so a four-chip host runs four replicas on four chips.
+        devices = jax.devices()
+        for i, spec in enumerate(specs):
+            self._spawn(spec, devices[i % len(devices)])
         self._owner: dict[int, _Replica] = {}     # rid -> replica
         self._est: dict[int, float] = {}          # rid -> placed estimate
         self._rr_place = 0                        # placement tie rotation
@@ -211,17 +221,19 @@ class FleetManager(ev.EventStreamMixin):
         self._respawns = 0
         self.lost: list[int] = []     # rids with no survivor to adopt them
 
-    def _spawn(self, spec: ReplicaSpec) -> _Replica:
-        """Build one replica from its spec, rebind it onto the shared
-        bus, and register it with a fresh health state machine."""
+    def _spawn(self, spec: ReplicaSpec, device: Any) -> _Replica:
+        """Build one replica from its spec on ``device``, rebind it onto
+        the shared bus, and register it with a fresh health state
+        machine."""
         threshold, alpha, suspect_limit = self._wd_params
-        engine = spec.make()
+        engine = spec.make(device)
         self._rebind(engine)
         rep = _Replica(
             spec, engine,
             ReplicaHealth(Watchdog(threshold=threshold, alpha=alpha),
                           suspect_limit=suspect_limit,
-                          name=spec.name, metrics=self.metrics))
+                          name=spec.name, metrics=self.metrics),
+            device)
         self.replicas.append(rep)
         return rep
 
@@ -414,13 +426,14 @@ class FleetManager(ev.EventStreamMixin):
         if self.replace_evicted and reason != "drained":
             # Capacity self-healing: rebuild a fresh replica from the
             # evicted slot's spec (new params/cache/health, suffixed
-            # name for uniqueness) BEFORE migrating, so the evacuated
-            # requests can land on the replacement too.  Drained
-            # replicas are deliberate removals and are not replaced.
-            fresh = ReplicaSpec(f"{rep.spec.name}~{self._respawns}",
-                                rep.spec.build)
+            # name for uniqueness, the slot's device) BEFORE migrating,
+            # so the evacuated requests can land on the replacement
+            # too.  Drained replicas are deliberate removals and are
+            # not replaced.
+            fresh = dataclasses.replace(
+                rep.spec, name=f"{rep.spec.name}~{self._respawns}")
             self._respawns += 1
-            self._spawn(fresh)
+            self._spawn(fresh, rep.device)
             self.replacements.append((rep.spec.name, fresh.name))
             if self.metrics is not None:
                 self.metrics.counter(

@@ -49,6 +49,13 @@ def _flatten_lead(x: jax.Array) -> tuple[jax.Array, tuple[int, ...]]:
     return x.reshape(-1, x.shape[-1]), lead
 
 
+def _pad_k(x: jax.Array, kp: int) -> jax.Array:
+    """Zero-pad activations to a tail-padded (``logical``) tensor's
+    stored K: the pad quants are zero, so the product is unchanged."""
+    return x if x.shape[-1] == kp else jnp.pad(
+        x, ((0, 0), (0, kp - x.shape[-1])))
+
+
 def quantized_matmul(x: jax.Array, w, *, force: Force = "auto",
                      out_dtype=None) -> jax.Array:
     """y[..., n] = x[..., k] @ dequant(w)[n, k] for Q8_0 / Q3_K weights.
@@ -62,17 +69,15 @@ def quantized_matmul(x: jax.Array, w, *, force: Force = "auto",
     use_pallas, interp = _use_pallas(force)
     if isinstance(w, Q8_0Tensor):
         n = w.qs.shape[0]
-        # Tail-padded (ragged) tensors go through the ref path: the
-        # Pallas kernels expect x and w to share a 32-aligned K.
-        if use_pallas and w.logical is None:
-            y = _q8.q8_matmul(xf, w.qs, w.d.astype(jnp.float32),
+        if use_pallas:
+            y = _q8.q8_matmul(_pad_k(xf, w.qs.shape[-1]), w.qs, w.d,
                               interpret=interp)
         else:
             y = ref.q8_matmul_ref(xf, w)
     elif isinstance(w, Q4_0Tensor):
         n = w.qs.shape[0]
-        if use_pallas and w.logical is None:
-            y = _q4.q4_matmul(xf, w.qs, w.d.astype(jnp.float32),
+        if use_pallas:
+            y = _q4.q4_matmul(_pad_k(xf, 2 * w.qs.shape[-1]), w.qs, w.d,
                               interpret=interp)
         else:
             y = ref.q4_matmul_ref(xf, w)
@@ -80,8 +85,7 @@ def quantized_matmul(x: jax.Array, w, *, force: Force = "auto",
         n = w.ql.shape[0]
         if use_pallas:
             sc = quant.unpack_scales6(w.scales).reshape(n, -1)
-            y = _q3k.q3k_matmul(xf, w.ql, w.qh, sc,
-                                w.d.astype(jnp.float32), interpret=interp)
+            y = _q3k.q3k_matmul(xf, w.ql, w.qh, sc, w.d, interpret=interp)
         else:
             y = ref.q3k_matmul_ref(xf, w)
     else:  # plain dense fallback: w is (N, K) array
@@ -101,8 +105,10 @@ def quantized_matmul_w8a8(x: jax.Array, w: Q8_0Tensor, *,
     xf, lead = _flatten_lead(x)
     xa = quant.quantize_q8_0(xf)
     xs = xa.d.astype(jnp.float32)
-    use_pallas, interp = _use_pallas(force)
-    if use_pallas:
+    # The w8a8 kernel runs only through the interpreter: Mosaic refuses
+    # its per-block split, so the TPU takes the XLA path.
+    _, interp = _use_pallas(force)
+    if interp:
         y = _q8.q8_matmul_w8a8(xa.qs, xs, w.qs, w.d.astype(jnp.float32),
                                interpret=interp)
     else:

@@ -13,7 +13,12 @@ happens in VMEM with vectorized shifts/masks on the VPU:
 * dequantized bf16 weights feed the MXU; accumulation is f32.
 
 Only ~3.4 bits/weight cross the HBM boundary, which is the paper's core
-insight applied to the TPU memory hierarchy.
+insight applied to the TPU memory hierarchy.  As in the other
+quantized kernels the K tile divides K and the unpack runs in the
+transposed domain (``repro.kernels.tiling``): packed bytes are widened
+and transposed to (rows, bn), so the 2-bit and 1-bit planes interleave
+along sublanes, and the wrapper hands the scales over lane-dense as
+(K/16, N) codes and (K/256, N) super-scales.
 """
 from __future__ import annotations
 
@@ -24,47 +29,44 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.quant import Q3K_SUB
+from repro.core.quant import N_SUB, Q3K_SUB, QK_K
+from repro.kernels.tiling import interleave_rows, k_block, repeat_rows
 
 DEFAULT_BM = 128
 DEFAULT_BN = 128
-DEFAULT_BK = 512
+DEFAULT_BK = 1024
+# Smallest K tile whose (bn, bk/8) high-bit block spans 128 lanes.
+K_ALIGN = 8 * 128
 
 
-def _unpack_q3_block(ql, qh, bn, bk):
-    """(bn,bk/4) uint8 + (bn,bk/8) uint8 -> (bn,bk) int8 in [-4,3]."""
-    shifts = jnp.arange(4, dtype=jnp.int32) * 2
-    low = (ql[..., None].astype(jnp.int32) >> shifts) & 3     # (bn,bk/4,4)
-    low = low.reshape(bn, bk)
-    hshifts = jnp.arange(8, dtype=jnp.int32)
-    hi = (qh[..., None].astype(jnp.int32) >> hshifts) & 1     # (bn,bk/8,8)
-    hi = hi.reshape(bn, bk)
-    return (low | (hi << 2)) - 4                              # int32 in [-4,3]
+def _unpack_q3_block(ql, qh):
+    """(bn,bk/4) uint8 + (bn,bk/8) uint8 -> (bk,bn) int32 in [-4,3]."""
+    ql = ql.astype(jnp.int32).T                               # (bk/4, bn)
+    qh = qh.astype(jnp.int32).T                               # (bk/8, bn)
+    low = interleave_rows([(ql >> (2 * j)) & 3 for j in range(4)])
+    hi = interleave_rows([(qh >> j) & 1 for j in range(8)])
+    return (low | (hi << 2)) - 4
 
 
 def _q3k_kernel(x_ref, ql_ref, qh_ref, sc_ref, d_ref, o_ref, acc_ref,
                 *, nk: int):
-    """x:(bm,bk) bf16 | ql:(bn,bk/4) | qh:(bn,bk/8) | sc:(bn,bk/16) int8
-    | d:(bn,bk/256) f32 -> o:(bm,bn) f32."""
+    """x:(bm,bk) bf16 | ql:(bn,bk/4) | qh:(bn,bk/8) | sc:(bk/16,bn) uint8
+    | d:(K/256,bn) f32 (whole column, sliced per K step) -> o:(bm,bn) f32."""
     k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    bn = ql_ref.shape[0]
-    bk = ql_ref.shape[1] * 4
-    q = _unpack_q3_block(ql_ref[...], qh_ref[...], bn, bk)    # OP_CVT53
+    nsup = x_ref.shape[1] // QK_K
+    q = _unpack_q3_block(ql_ref[...], qh_ref[...])            # OP_CVT53
     # Effective scale per 16-weight sub-block: d * (sc - 32).
-    nsb = bk // Q3K_SUB
-    d = d_ref[...]                                            # (bn, bk/256)
-    d16 = jnp.repeat(d, nsb // d.shape[1], axis=1)            # (bn, nsb)
-    eff = d16 * (sc_ref[...].astype(jnp.float32) - 32.0)
-    w = (q.astype(jnp.float32).reshape(bn, nsb, Q3K_SUB)
-         * eff[:, :, None]).reshape(bn, bk).astype(jnp.bfloat16)
-    acc_ref[...] += jax.lax.dot_general(
-        x_ref[...], w, dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    d = d_ref[pl.ds(k * nsup, nsup), :]                       # (bk/256, bn)
+    sc = sc_ref[...].astype(jnp.int32).astype(jnp.float32)    # (bk/16, bn)
+    eff = repeat_rows(d, N_SUB) * (sc - 32.0)
+    w = q.astype(jnp.float32) * repeat_rows(eff, Q3K_SUB)
+    acc_ref[...] += jnp.dot(x_ref[...], w.astype(jnp.bfloat16),
+                            preferred_element_type=jnp.float32)
 
     @pl.when(k == nk - 1)
     def _done():
@@ -78,13 +80,14 @@ def q3k_matmul(x: jax.Array, ql: jax.Array, qh: jax.Array,
     """y = x @ dequant(w).T with w in Q3_K.
 
     x: (M, K) bf16; ql: (N, K/4) uint8; qh: (N, K/8) uint8;
-    sc: (N, K/16) uint8 6-bit codes; d: (N, K/256) f32. Returns (M, N) f32.
+    sc: (N, K/16) uint8 6-bit codes; d: (N, K/256) super-scales.
+    Returns (M, N) f32.
     """
     m, k = x.shape
     n = ql.shape[0]
-    bm, bn, bk = min(bm, m), min(bn, n), min(bk, k)
-    assert bk % 256 == 0, "bk must cover whole Q3_K super-blocks"
-    nk = pl.cdiv(k, bk)
+    assert k % QK_K == 0 and d.shape == (n, k // QK_K)
+    bm, bn, bk = min(bm, m), min(bn, n), k_block(k, bk, K_ALIGN)
+    nk = k // bk
     grid = (pl.cdiv(m, bm), pl.cdiv(n, bn), nk)
     return pl.pallas_call(
         functools.partial(_q3k_kernel, nk=nk),
@@ -93,11 +96,11 @@ def q3k_matmul(x: jax.Array, ql: jax.Array, qh: jax.Array,
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bn, bk // 4), lambda i, j, kk: (j, kk)),
             pl.BlockSpec((bn, bk // 8), lambda i, j, kk: (j, kk)),
-            pl.BlockSpec((bn, bk // 16), lambda i, j, kk: (j, kk)),
-            pl.BlockSpec((bn, bk // 256), lambda i, j, kk: (j, kk)),
+            pl.BlockSpec((bk // Q3K_SUB, bn), lambda i, j, kk: (kk, j)),
+            pl.BlockSpec((k // QK_K, bn), lambda i, j, kk: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-    )(x.astype(jnp.bfloat16), ql, qh, sc, d)
+    )(x.astype(jnp.bfloat16), ql, qh, sc.T, d.astype(jnp.float32).T)

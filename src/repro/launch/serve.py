@@ -52,6 +52,7 @@ from repro.engine import (AsrEngine, AsrEngineConfig, CostModel,
                           LMEngineConfig, Rejected, ReplicaSpec,
                           SpecDecodeConfig, TokenDelta,
                           TranscribeRequest, calibrate)
+from repro.launch import compile_cache
 from repro.models.frontend import synthetic_audio
 from repro.models.transformer import init_lm
 from repro.serving import ContinuousBatcher, Request
@@ -109,6 +110,7 @@ def main() -> None:
                          "Chrome trace-event JSON (Perfetto-loadable) "
                          "to PATH (implies the metrics layer)")
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = get_config(args.arch)
     if jax.default_backend() == "cpu":

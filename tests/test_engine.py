@@ -178,7 +178,12 @@ def test_per_request_guidance_scale_applies(sd_params, toks):
 def test_preview_stream_matches_fused_scan(sd_params, toks):
     """The segmented (preview-streaming) program path must reproduce
     the fused single-scan result, and stream Progress + PreviewLatent
-    events at the requested cadence."""
+    events at the requested cadence.
+
+    The two paths are different XLA programs (one scan vs encode +
+    per-step + decode programs) that fuse the bf16 UNet/VAE math
+    differently, so the [-1, 1] images agree to a couple of bf16 ulps
+    (2**-6), not bit for bit."""
     for sampler, steps in (("ddim", 3), ("euler", 2)):
         e1 = DiffusionEngine(sd_params, TINY_SD, max_batch=1)
         e1.submit(GenerateRequest(rid=0, tokens=toks[0], sampler=sampler,
@@ -191,7 +196,7 @@ def test_preview_stream_matches_fused_scan(sd_params, toks):
         evs = list(h.events())
         np.testing.assert_allclose(
             np.asarray(h.result().image, np.float32), ref,
-            atol=1e-5, rtol=1e-5)
+            atol=2.0 ** -6, rtol=0)
         previews = [e for e in evs if isinstance(e, PreviewLatent)]
         assert [p.step for p in previews] == list(range(1, steps + 1))
         assert all(p.latent.shape == (8, 8, 4) for p in previews)

@@ -9,7 +9,9 @@ from repro.core import quant
 from repro.kernels import ops, ref
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.q3k_matmul import q3k_matmul
+from repro.kernels.q4_matmul import q4_matmul
 from repro.kernels.q8_matmul import q8_matmul, q8_matmul_w8a8
+from repro.kernels.tiling import k_block
 
 
 def _xw(m, k, n, seed=0, dtype=jnp.float32):
@@ -19,8 +21,14 @@ def _xw(m, k, n, seed=0, dtype=jnp.float32):
     return x, w
 
 
+# K that is not a multiple of the 512 K tile (SD-Turbo's 640, 768 and
+# 1280): a kernel that read a partial last K block returned NaN here.
+ODD_K = [(16, 640, 128), (8, 768, 64), (8, 1280, 136)]
+
+
 @pytest.mark.parametrize("m,k,n", [(8, 64, 16), (32, 256, 64),
-                                   (128, 1024, 256), (17, 512, 96)])
+                                   (128, 1024, 256), (17, 512, 96)]
+                         + ODD_K)
 def test_q8_dequant_kernel_matches_oracle(m, k, n):
     x, w = _xw(m, k, n, seed=m)
     wq = quant.quantize_q8_0(w)
@@ -30,7 +38,28 @@ def test_q8_dequant_kernel_matches_oracle(m, k, n):
                                atol=2e-5, rtol=1e-5)
 
 
-@pytest.mark.parametrize("m,k,n", [(8, 64, 16), (64, 512, 128)])
+@pytest.mark.parametrize("m,k,n", ODD_K)   # small K: test_extensions.py
+def test_q4_kernel_matches_oracle(m, k, n):
+    x, w = _xw(m, k, n, seed=m + 3)
+    wq = quant.quantize_q4_0(w)
+    want = ref.q4_matmul_ref(x, wq)
+    got = q4_matmul(x, wq.qs, wq.d.astype(jnp.float32), interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("k,pref,align,want", [
+    (320, 512, 256, 320), (640, 512, 256, 640), (768, 512, 256, 256),
+    (1280, 512, 256, 256), (5120, 512, 256, 512), (768, 1024, 1024, 768),
+    (2304, 1024, 1024, 2304), (5120, 1024, 1024, 1024)])
+def test_k_block_divides_k(k, pref, align, want):
+    bk = k_block(k, pref, align)
+    assert bk == want and k % bk == 0
+    assert bk == k or (bk % align == 0 and bk <= pref)
+
+
+@pytest.mark.parametrize("m,k,n", [(8, 64, 16), (64, 512, 128),
+                                   (8, 640, 32)])
 def test_q8_w8a8_kernel_matches_oracle(m, k, n):
     x, w = _xw(m, k, n, seed=m + 1)
     wq = quant.quantize_q8_0(w)
@@ -43,7 +72,9 @@ def test_q8_w8a8_kernel_matches_oracle(m, k, n):
                                atol=2e-5, rtol=1e-5)
 
 
-@pytest.mark.parametrize("m,k,n", [(8, 256, 16), (32, 1024, 64)])
+@pytest.mark.parametrize("m,k,n", [(8, 256, 16), (32, 1024, 64),
+                                   (8, 768, 32), (8, 1280, 16),
+                                   (8, 2304, 16), (8, 3072, 16)])
 @pytest.mark.parametrize("scale_bits", [6, 5])
 def test_q3k_kernel_matches_oracle(m, k, n, scale_bits):
     x, w = _xw(m, k, n, seed=m + 2)
@@ -95,6 +126,19 @@ def test_chunked_attention_matches_ref():
     want = ref.flash_attention_ref(q, k, v, causal=True)
     got = ops._chunked_attention(q, k, v, causal=True, window=None,
                                  scale=d ** -0.5, q_chunk=128)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("fmt", ["q8_0", "q4_0"])
+def test_quantized_matmul_ragged_k_runs_the_kernel(fmt):
+    """A tail-padded (``logical``) tensor goes through the kernel with
+    zero-padded activations, not a silent fallback to the oracle."""
+    x, w = _xw(5, 100, 48, seed=4)
+    wq = quant.quantize(w, fmt)
+    assert wq.logical == 100
+    want = (ref.q8_matmul_ref if fmt == "q8_0" else ref.q4_matmul_ref)(x, wq)
+    got = ops.quantized_matmul(x, wq, force="interpret")
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=1e-5)
 
