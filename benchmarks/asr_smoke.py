@@ -12,7 +12,7 @@ Drives the ``AsrEngine`` (PR 9) as the third modality behind one
 * **Fused enc-dec prefill wins** — the fused paged decoder prefill
   emits bit-identical transcripts to the retained decode-step scan at
   strictly fewer kernel launches (the gated row leads with the launch
-  count so ``benchmarks/compare.py`` treats it as tight lower-better).
+  count).
 * **Failover without loss** — with 2 ASR replicas and one killed
   mid-encode by a deterministic ``FaultInjector``, every transcript is
   bit-identical to a single-replica run of the same seeds: migrated

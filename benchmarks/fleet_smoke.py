@@ -220,9 +220,8 @@ def smoke_throughput_scaling() -> list[str]:
         return max(r["steps"] for r in fleet.stats()["replicas"])
 
     m1, m3 = makespan(1), makespan(3)
-    # req/s at a nominal 10 ms quantum, for the human-readable detail.
-    # The value leads with the speedup ratio so the trajectory
-    # comparator (benchmarks/compare.py) gates on it directly.
+    # req/s at a nominal 10 ms quantum, for the human-readable detail;
+    # the value leads with the speedup ratio.
     rps1, rps3 = n_req / (m1 * 0.01), n_req / (m3 * 0.01)
     rows = [f"fleet_smoke/scaling,{rps3 / rps1:.2f}x speedup at 3 "
             f"replicas,makespan {m3} quanta vs {m1}; "

@@ -75,6 +75,7 @@ from repro.engine.config import EngineConfig, UNSET, resolve
 from repro.models import clip as clip_mod
 from repro.models import unet as unet_mod
 from repro.models import vae as vae_mod
+from repro.obs import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -128,6 +129,38 @@ def steps_bucket(steps: int) -> int:
     return b
 
 
+def _encode(params, clip_cfg, tokens, neg_tokens, use_cfg: bool):
+    """CLIP on the prompts (and the negative prompts under CFG), under
+    the ``clip`` scope."""
+    with jax.named_scope("clip"):
+        ctx = clip_mod.clip_encode(params["clip"], clip_cfg, tokens)
+        ctx_u = (clip_mod.clip_encode(params["clip"], clip_cfg, neg_tokens)
+                 if use_cfg else None)
+    return ctx, ctx_u
+
+
+def _eps(params, cfg: SDConfig, xm, tb, ctx, ctx_u, g):
+    """The noise prediction of one solver step: one UNet evaluation per
+    guidance branch, each under the ``unet`` scope."""
+    def unet(c):
+        with jax.named_scope("unet"):
+            return unet_mod.apply_unet(params["unet"], cfg.unet,
+                                       xm.astype(jnp.bfloat16), tb,
+                                       c).astype(jnp.float32)
+
+    eps = unet(ctx)
+    if ctx_u is not None:
+        eps_u = unet(ctx_u)
+        eps = eps_u + g * (eps - eps_u)
+    return eps
+
+
+def _decode(params, cfg: SDConfig, x0):
+    with jax.named_scope("vae"):
+        return vae_mod.apply_vae_decoder(params["vae"], cfg.vae,
+                                         x0.astype(jnp.bfloat16))
+
+
 def build_denoise(cfg: SDConfig, sampler_name: str, use_cfg: bool, *,
                   decode: bool = True) -> Callable:
     """Build the pure denoise program for one sampler / guidance mode.
@@ -136,7 +169,9 @@ def build_denoise(cfg: SDConfig, sampler_name: str, use_cfg: bool, *,
     mapping ``(B, text_len)`` prompts and ``(B, hw, hw, 4)`` unit noise
     to images (or x0 latents with ``decode=False``).  Fully traceable —
     the engine jits it; ``pipeline.generate`` and ``jax.eval_shape``
-    callers use it directly.
+    callers use it directly.  CLIP, every UNet evaluation and the VAE
+    run under the ``clip``/``unet``/``vae`` named scopes, so a device
+    trace attributes each op to its model.
     """
     sampler = samplers_mod.get_sampler(sampler_name)
     sched = sched_mod.NoiseSchedule()
@@ -144,23 +179,14 @@ def build_denoise(cfg: SDConfig, sampler_name: str, use_cfg: bool, *,
 
     def fn(params, tokens, neg_tokens, gscale, noise, plan):
         b = tokens.shape[0]
-        ctx = clip_mod.clip_encode(params["clip"], clip_cfg, tokens)
-        ctx_u = (clip_mod.clip_encode(params["clip"], clip_cfg, neg_tokens)
-                 if use_cfg else None)
+        ctx, ctx_u = _encode(params, clip_cfg, tokens, neg_tokens, use_cfg)
         x = sampler.init_latent(noise.astype(jnp.float32), plan)
         g = gscale[:, None, None, None]
 
         def body(x, step):
             xm, t = sampler.model_input(x, step)
             tb = jnp.broadcast_to(t, (b,)).astype(jnp.int32)
-            eps = unet_mod.apply_unet(params["unet"], cfg.unet,
-                                      xm.astype(jnp.bfloat16), tb,
-                                      ctx).astype(jnp.float32)
-            if use_cfg:
-                eps_u = unet_mod.apply_unet(params["unet"], cfg.unet,
-                                            xm.astype(jnp.bfloat16), tb,
-                                            ctx_u).astype(jnp.float32)
-                eps = eps_u + g * (eps - eps_u)
+            eps = _eps(params, cfg, xm, tb, ctx, ctx_u, g)
             x_new = sampler.update(sched, x, eps, step)
             return jnp.where(step["valid"], x_new, x), None
 
@@ -168,8 +194,7 @@ def build_denoise(cfg: SDConfig, sampler_name: str, use_cfg: bool, *,
         x0 = sampler.finalize(x)
         if not decode:
             return x0
-        return vae_mod.apply_vae_decoder(params["vae"], cfg.vae,
-                                         x0.astype(jnp.bfloat16))
+        return _decode(params, cfg, x0)
     return fn
 
 
@@ -179,10 +204,7 @@ def build_encode(cfg: SDConfig, use_cfg: bool) -> Callable:
     clip_cfg = cfg.clip_cfg()
 
     def fn(params, tokens, neg_tokens):
-        ctx = clip_mod.clip_encode(params["clip"], clip_cfg, tokens)
-        ctx_u = (clip_mod.clip_encode(params["clip"], clip_cfg, neg_tokens)
-                 if use_cfg else None)
-        return ctx, ctx_u
+        return _encode(params, clip_cfg, tokens, neg_tokens, use_cfg)
     return fn
 
 
@@ -201,14 +223,7 @@ def build_denoise_step(cfg: SDConfig, sampler_name: str,
         g = gscale[:, None, None, None]
         xm, t = sampler.model_input(x, step)
         tb = jnp.broadcast_to(t, (b,)).astype(jnp.int32)
-        eps = unet_mod.apply_unet(params["unet"], cfg.unet,
-                                  xm.astype(jnp.bfloat16), tb,
-                                  ctx).astype(jnp.float32)
-        if use_cfg:
-            eps_u = unet_mod.apply_unet(params["unet"], cfg.unet,
-                                        xm.astype(jnp.bfloat16), tb,
-                                        ctx_u).astype(jnp.float32)
-            eps = eps_u + g * (eps - eps_u)
+        eps = _eps(params, cfg, xm, tb, ctx, ctx_u if use_cfg else None, g)
         x_new = sampler.update(sched, x, eps, step)
         return jnp.where(step["valid"], x_new, x)
     return fn
@@ -220,9 +235,7 @@ def build_finalize_decode(cfg: SDConfig, sampler_name: str) -> Callable:
     sampler = samplers_mod.get_sampler(sampler_name)
 
     def fn(params, x):
-        x0 = sampler.finalize(x)
-        return vae_mod.apply_vae_decoder(params["vae"], cfg.vae,
-                                         x0.astype(jnp.bfloat16))
+        return _decode(params, cfg, sampler.finalize(x))
     return fn
 
 
@@ -290,6 +303,10 @@ class DiffusionEngine(ev.EventStreamMixin):
 
     # ------------------------------------------------------------ API
     def submit(self, request: GenerateRequest) -> ev.RequestHandle:
+        with span("engine.submit", rid=request.rid):
+            return self._submit(request)
+
+    def _submit(self, request: GenerateRequest) -> ev.RequestHandle:
         samplers_mod.get_sampler(request.sampler)   # fail fast on typos
         if request.steps < 1:
             raise ValueError(f"steps must be >= 1, got {request.steps}")
@@ -438,7 +455,18 @@ class DiffusionEngine(ev.EventStreamMixin):
     def step(self) -> int:
         """One scheduling quantum: advance the in-flight segmented
         batch by one denoise step, or pop + run a new micro-batch;
-        returns #requests progressed (0 if idle)."""
+        returns #requests progressed (0 if idle).
+
+        Host work is named on the profiler's clock (``repro.obs.span``):
+        ``engine.step`` holds ``engine.admit`` (pop and events),
+        ``engine.pack`` (request rows to device arrays),
+        ``engine.launch`` (each jitted call, with the batch's ``rids``,
+        ``rows``, ``bucket``, ``steps``, ``sampler`` and ``cfg``) and
+        ``engine.retire`` (row slices and ``Finished``)."""
+        with span("engine.step"):
+            return self._step()
+
+    def _step(self) -> int:
         if self.cost_model is not None and self.queue:
             self._sweep_infeasible()
         if self._inflight is not None:
@@ -449,6 +477,17 @@ class DiffusionEngine(ev.EventStreamMixin):
             return 0
         self.quanta += 1
         self._obs_sched()
+        with span("engine.admit"):
+            batch, gkey = self._admit()
+        if gkey[4]:                      # preview_every > 0: segmented
+            self._start_segmented(batch, gkey)
+            return self._segment_quantum()
+        self._run_batch(batch, gkey)
+        return len(batch)
+
+    def _admit(self) -> tuple[list[GenerateRequest], tuple]:
+        """Pop the next micro-batch: the EDF seed and up to
+        ``max_batch - 1`` queued requests of its compile group."""
         seed = min(self.queue, key=self._edf_key)
         gkey = self._group_key(seed)
         batch: list[GenerateRequest] = [seed]
@@ -467,11 +506,7 @@ class DiffusionEngine(ev.EventStreamMixin):
                               step=0, total=gkey[1])
             else:
                 self.bus.emit(ev.Admitted, r.rid, slot=i)
-        if gkey[4]:                      # preview_every > 0: segmented
-            self._start_segmented(batch, gkey)
-            return self._segment_quantum()
-        self._run_batch(batch, gkey)
-        return len(batch)
+        return batch, gkey
 
     def run(self, max_steps: int = 10_000) -> list[GenerateResult]:
         for _ in range(max_steps):
@@ -528,18 +563,16 @@ class DiffusionEngine(ev.EventStreamMixin):
         jax.block_until_ready(out)
         self.cost_model.observe(key, self.bus.clock() - t0)
 
-    def _obs_phase(self, phase: str, t0: float, out, rids: list,
-                   args: dict | None = None) -> None:
-        """Phase telemetry mark (histogram + trace span).  Unlike the
-        cost-model ``_observe`` this never skips first-trace quanta —
-        phase counts must reconcile exactly with emitted events, so
-        first observations simply include compile time (documented in
-        the metric help text)."""
-        if self.metrics is None:
-            return
-        jax.block_until_ready(out)
-        self.metrics.phase("diffusion", phase, t0, self.bus.clock(),
-                           rids=rids, args=args)
+    def _launch(self, phase: str, rids: list, **args) -> span:
+        """The span of one jitted call: on the profiler's clock, and
+        under a ``Telemetry`` one ``phase_seconds`` observation and
+        trace span of the call's host time.  Unlike the cost-model
+        ``_observe`` it never skips first-trace quanta (phase counts
+        reconcile exactly with emitted events, so a first observation
+        includes compile time) and never waits for the device."""
+        return span("engine.launch", self.metrics, phase=phase,
+                    engine="diffusion", clock=self.bus.clock, rids=rids,
+                    weight_quant=self.weight_quant, **args)
 
     def _obs_sched(self) -> None:
         if self.metrics is None:
@@ -609,44 +642,46 @@ class DiffusionEngine(ev.EventStreamMixin):
     # ------------------------------------------------- fused scan path
     def _run_batch(self, reqs: list[GenerateRequest], gkey: tuple) -> None:
         sampler_name, steps, hw, use_cfg = gkey[:4]
-        toks, negs, scales, noises = self._pack(reqs, hw)
         sbucket = steps_bucket(steps)
-        sampler = samplers_mod.get_sampler(sampler_name)
-        plan = sampler.plan(sched_mod.NoiseSchedule(), steps, sbucket)
-        fn = self._compiled(sampler_name, sbucket, hw, use_cfg)
+        with span("engine.pack", rows=len(reqs)):
+            toks, negs, scales, noises = self._pack(reqs, hw)
+            sampler = samplers_mod.get_sampler(sampler_name)
+            plan = sampler.plan(sched_mod.NoiseSchedule(), steps, sbucket)
+            fn = self._compiled(sampler_name, sbucket, hw, use_cfg)
         t0, tr0 = self.bus.clock(), self.traces
-        imgs = fn(self.params, toks, negs, scales, noises, plan)
+        with self._launch("fused", [r.rid for r in reqs], rows=len(reqs),
+                          bucket=sbucket, steps=steps, sampler=sampler_name,
+                          cfg=use_cfg):
+            imgs = fn(self.params, toks, negs, scales, noises, plan)
         self._observe(("diff", self.cfg.name, "fused", sampler_name,
                        sbucket, hw, use_cfg, self.max_batch,
                        self.weight_quant), t0, tr0, imgs)
-        self._obs_phase("fused", t0, imgs, [r.rid for r in reqs],
-                        args={"steps": steps, "batch": len(reqs),
-                              "weight_quant": self.weight_quant})
-        for i, r in enumerate(reqs):
-            res = GenerateResult(
-                rid=r.rid, image=imgs[i], sampler=sampler_name,
-                steps=steps, seed=r.seed, decode_steps=steps)
-            self.finished.append(res)
-            self.bus.emit(ev.Finished, r.rid, result=res)
+        with span("engine.retire", rows=len(reqs)):
+            for i, r in enumerate(reqs):
+                res = GenerateResult(
+                    rid=r.rid, image=imgs[i], sampler=sampler_name,
+                    steps=steps, seed=r.seed, decode_steps=steps)
+                self.finished.append(res)
+                self.bus.emit(ev.Finished, r.rid, result=res)
 
     # ------------------------------------------------- segmented path
     def _start_segmented(self, reqs: list[GenerateRequest],
                          gkey: tuple) -> None:
         sampler_name, steps, hw, use_cfg = gkey[:4]
-        toks, negs, scales, noises = self._pack(reqs, hw)
-        enc = self._counted_jit(("enc", use_cfg, self.max_batch),
-                                build_encode(self.cfg, use_cfg))
+        sampler = samplers_mod.get_sampler(sampler_name)
+        with span("engine.pack", rows=len(reqs)):
+            toks, negs, scales, noises = self._pack(reqs, hw)
+            enc = self._counted_jit(("enc", use_cfg, self.max_batch),
+                                    build_encode(self.cfg, use_cfg))
+            # Unpadded plan: the 1-step segment program serves any step
+            # count, so segmented requests never pay pow2 padding steps.
+            plan = sampler.plan(sched_mod.NoiseSchedule(), steps, steps)
         t0, tr0 = self.bus.clock(), self.traces
-        ctx, ctx_u = enc(self.params, toks, negs)
+        with self._launch("clip", [r.rid for r in reqs], rows=len(reqs),
+                          cfg=use_cfg):
+            ctx, ctx_u = enc(self.params, toks, negs)
         self._observe(("diff", self.cfg.name, "clip", use_cfg,
                        self.max_batch, self.weight_quant), t0, tr0, ctx)
-        self._obs_phase("clip", t0, ctx, [r.rid for r in reqs],
-                        args={"batch": len(reqs),
-                              "weight_quant": self.weight_quant})
-        sampler = samplers_mod.get_sampler(sampler_name)
-        # Unpadded plan: the 1-step segment program serves any step
-        # count, so segmented requests never pay pow2 padding steps.
-        plan = sampler.plan(sched_mod.NoiseSchedule(), steps, steps)
         self._inflight = dict(
             reqs=reqs, key=(sampler_name, steps, hw, use_cfg),
             x=sampler.init_latent(noises, plan), ctx=ctx, ctx_u=ctx_u,
@@ -666,15 +701,14 @@ class DiffusionEngine(ev.EventStreamMixin):
             ("seg", sampler_name, hw, use_cfg, self.max_batch),
             build_denoise_step(self.cfg, sampler_name, use_cfg))
         t0, tr0 = self.bus.clock(), self.traces
-        st["x"] = fn(self.params, st["ctx"], st["ctx_u"], st["g"],
-                     st["x"], step_slice)
+        with self._launch("unet_step", [r.rid for _row, r in live],
+                          rows=len(live), bucket=steps, steps=steps,
+                          sampler=sampler_name, cfg=use_cfg, step=i + 1):
+            st["x"] = fn(self.params, st["ctx"], st["ctx_u"], st["g"],
+                         st["x"], step_slice)
         self._observe(("diff", self.cfg.name, "unet_step", sampler_name,
                        hw, use_cfg, self.max_batch, self.weight_quant),
                       t0, tr0, st["x"])
-        self._obs_phase("unet_step", t0, st["x"],
-                        [r.rid for _row, r in live],
-                        args={"step": i + 1, "total": steps,
-                              "weight_quant": self.weight_quant})
         st["i"] = i + 1
         sampler = samplers_mod.get_sampler(sampler_name)
         at_stride = [(row, r) for row, r in live
@@ -690,14 +724,12 @@ class DiffusionEngine(ev.EventStreamMixin):
                                     build_finalize_decode(self.cfg,
                                                           sampler_name))
             t0, tr0 = self.bus.clock(), self.traces
-            pv_imgs = dec(self.params, st["x"])
+            with self._launch("vae", [r.rid for _row, r in at_stride],
+                              rows=len(at_stride), preview=True):
+                pv_imgs = dec(self.params, st["x"])
             self._observe(("diff", self.cfg.name, "vae", hw,
                            self.max_batch, self.weight_quant), t0, tr0,
                           pv_imgs)
-            self._obs_phase("vae", t0, pv_imgs,
-                            [r.rid for _row, r in at_stride],
-                            args={"preview": True,
-                                  "weight_quant": self.weight_quant})
         for row, r in live:
             self.bus.emit(ev.Progress, r.rid, step=st["i"], total=steps,
                           phase="denoise")
@@ -716,18 +748,18 @@ class DiffusionEngine(ev.EventStreamMixin):
                                     build_finalize_decode(self.cfg,
                                                           sampler_name))
             t0, tr0 = self.bus.clock(), self.traces
-            imgs = dec(self.params, st["x"])
+            with self._launch("vae", [r.rid for _row, r in live],
+                              rows=len(live)):
+                imgs = dec(self.params, st["x"])
             self._observe(("diff", self.cfg.name, "vae", hw,
                            self.max_batch, self.weight_quant), t0, tr0,
                           imgs)
-            self._obs_phase("vae", t0, imgs,
-                            [r.rid for _row, r in live],
-                            args={"weight_quant": self.weight_quant})
-            for row, r in live:
-                res = GenerateResult(
-                    rid=r.rid, image=imgs[row], sampler=sampler_name,
-                    steps=steps, seed=r.seed, decode_steps=steps)
-                self.finished.append(res)
-                self.bus.emit(ev.Finished, r.rid, result=res)
+            with span("engine.retire", rows=len(live)):
+                for row, r in live:
+                    res = GenerateResult(
+                        rid=r.rid, image=imgs[row], sampler=sampler_name,
+                        steps=steps, seed=r.seed, decode_steps=steps)
+                    self.finished.append(res)
+                    self.bus.emit(ev.Finished, r.rid, result=res)
             self._inflight = None
         return len(live)

@@ -103,6 +103,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
+        name="flash_attention",
         interpret=interpret,
     )(qf, kf, vf)
     return out.reshape(b, h, sq, d)

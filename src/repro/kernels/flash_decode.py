@@ -89,6 +89,7 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((g, 1), jnp.float32),
             pltpu.VMEM((g, d), jnp.float32),
         ],
+        name="flash_decode",
         interpret=interpret,
     )(kv_len.astype(jnp.int32), qf, kf, vf)
     return out.reshape(b, h, g, d)
@@ -185,6 +186,7 @@ def flash_decode_paged(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         functools.partial(_paged_decode_kernel, scale=scale, mb=mb, bs=bs),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, g, d), q.dtype),
+        name="flash_decode_paged",
         interpret=interpret,
     )(block_tables.astype(jnp.int32), positions.astype(jnp.int32),
       q, k_pool, v_pool)

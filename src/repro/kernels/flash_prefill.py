@@ -186,6 +186,7 @@ def flash_prefill_paged(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
         # Inputs are numbered incl. the two scalar-prefetch operands:
         # 5/6 are k_pool/v_pool -> outputs 1/2 (in-place KV writes).
         input_output_aliases={5: 1, 6: 2},
+        name="flash_prefill_paged",
         interpret=interpret,
     )(block_table.astype(jnp.int32), pos0, qf, knf, vnf, k_pool, v_pool)
     return out.reshape(h, t, g, d).transpose(1, 0, 2, 3), kp, vp
@@ -401,6 +402,7 @@ def flash_prefill_paged_q8(q: jax.Array, k_new: jax.Array,
         # Inputs numbered incl. the two scalar-prefetch operands: 5..8
         # are kq/vq/ks/vs pools -> outputs 1..4 (in-place KV writes).
         input_output_aliases={5: 1, 6: 2, 7: 3, 8: 4},
+        name="flash_prefill_paged_q8",
         interpret=interpret,
     )(block_table.astype(jnp.int32), pos0, qf, knf, vnf,
       kq_pool, vq_pool, ks_pool, vs_pool)

@@ -102,5 +102,6 @@ def q3k_matmul(x: jax.Array, ql: jax.Array, qh: jax.Array,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        name="q3k_matmul",
         interpret=interpret,
     )(x.astype(jnp.bfloat16), ql, qh, sc.T, d.astype(jnp.float32).T)

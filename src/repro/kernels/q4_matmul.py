@@ -70,5 +70,6 @@ def q4_matmul(x: jax.Array, qs: jax.Array, ws: jax.Array,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        name="q4_matmul",
         interpret=interpret,
     )(x.astype(jnp.bfloat16), qs, ws.astype(jnp.float32).T)

@@ -82,6 +82,7 @@ def q8_matmul(x: jax.Array, wq: jax.Array, ws: jax.Array,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        name="q8_matmul",
         interpret=interpret,
     )(x.astype(jnp.bfloat16), wq, ws.astype(jnp.float32).T)
 
@@ -141,5 +142,6 @@ def q8_matmul_w8a8(xq: jax.Array, xs: jax.Array, wq: jax.Array,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        name="q8_matmul_w8a8",
         interpret=interpret,
     )(xq, xs, wq, ws)

@@ -72,14 +72,19 @@ def init_conv(key, in_ch: int, out_ch: int, k: int = 3, *,
 
 
 def apply_conv(p: Conv, x: jax.Array, stride: int = 1) -> jax.Array:
-    """x: (B, H, W, C) -> (B, H', W', out_ch) via im2col + mul_mat."""
+    """x: (B, H, W, C) -> (B, H', W', out_ch) via im2col + mul_mat.
+
+    The whole convolution (patches, matmul, bias) runs under the
+    ``conv`` named scope, whatever implements it, so a device trace
+    measures every convolution against the same work."""
     k = p.k
     pad = (k - 1) // 2
-    patches = jax.lax.conv_general_dilated_patches(
-        x, (k, k), (stride, stride), ((pad, pad), (pad, pad)),
-        dimension_numbers=("NHWC", "HWIO", "NHWC"))
-    # patches: (B, H', W', C*k*k) — the im2col buffer GGML builds.
-    return apply_linear(p.lin, patches)
+    with jax.named_scope("conv"):
+        patches = jax.lax.conv_general_dilated_patches(
+            x, (k, k), (stride, stride), ((pad, pad), (pad, pad)),
+            dimension_numbers=("NHWC", "HWIO", "NHWC"))
+        # patches: (B, H', W', C*k*k) — the im2col buffer GGML builds.
+        return apply_linear(p.lin, patches)
 
 
 # ------------------------------------------------------------ groupnorm

@@ -23,6 +23,11 @@ bit-identical; pass a :class:`Telemetry` (or a bare
   consumers (``ReplicaHealth``, ``CostModel``) work with either a
   ``Telemetry`` or a bare registry.
 
+:class:`span` (``repro.obs.span``) names a region of host work on the
+profiler's clock; given a ``Telemetry`` and a phase it also calls
+:meth:`Telemetry.phase` for the same region, so the diffusion engine's
+phase spans and its profiler annotations come from one call site.
+
 Attach to the FINAL bus: ``EngineRouter`` / ``FleetManager`` rebind
 engine buses onto a shared one during construction, and subscriptions
 live on the bus object itself.
@@ -36,13 +41,14 @@ from repro.obs.metrics import (DEFAULT_ERROR_BUCKETS,
                                DEFAULT_TIME_BUCKETS,
                                SNAPSHOT_SCHEMA_VERSION, Counter, Gauge,
                                Histogram, MetricsRegistry)
+from repro.obs.span import span
 from repro.obs.trace import Marker, Span, TraceRecorder
 
 TERMINAL_EVENT_NAMES = ("Finished", "Cancelled", "Rejected")
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
-    "TraceRecorder", "Span", "Marker", "Telemetry",
+    "TraceRecorder", "Span", "Marker", "Telemetry", "span",
     "DEFAULT_TIME_BUCKETS", "DEFAULT_ERROR_BUCKETS",
     "SNAPSHOT_SCHEMA_VERSION", "TERMINAL_EVENT_NAMES",
 ]
@@ -128,8 +134,11 @@ class Telemetry:
         cost-model-aligned phase name and hand the span to the
         tracer."""
         self.histogram(
-            "phase_seconds", "compute quantum duration by phase "
-            "(first observation per shape includes jit compile)",
+            "phase_seconds", "compute quantum duration by phase: host "
+            "time of the dispatch for diffusion (device time is under the "
+            "clip/unet/vae scopes of a profiler trace), until the outputs "
+            "are ready for lm and asr; the first observation per shape "
+            "includes jit compile",
             labels=("engine", "phase")
         ).observe(t1 - t0, engine=engine, phase=phase)
         if self.tracer is not None:

@@ -31,8 +31,8 @@ Design points, deliberately Prometheus-shaped:
   :meth:`MetricsRegistry.write_snapshot` emit the *same versioned
   JSON record schema* as ``benchmarks/common.py`` (schema_version 1,
   ``{bench, name, value, detail}`` entries) so metric snapshots ride
-  the existing CI perf-trajectory harness (``compare.py`` diffs them
-  run-over-run like any other suite).  ``benchmarks/obs_smoke.py``
+  the CI perf-trajectory artifacts like any other suite.
+  ``benchmarks/obs_smoke.py``
   cross-validates a written snapshot against
   ``benchmarks.common.validate_record``.
 
@@ -312,7 +312,7 @@ class MetricsRegistry:
         """Versioned JSON record in the ``benchmarks/common.py`` schema
         (schema_version, suite, env, ``{bench, name, value, detail}``
         entries) — what CI uploads as a ``BENCH_<suite>.json``-style
-        artifact and ``compare.py`` diffs run-over-run."""
+        artifact."""
         entries = []
         for row in self.rows():
             name, value, detail = (row.split(",", 2) + [""])[:3]

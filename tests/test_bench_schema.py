@@ -12,9 +12,6 @@ import pytest
 from benchmarks.common import (BENCH_SCHEMA_VERSION, bench_record,
                                parse_row, validate_record,
                                write_bench_json)
-from benchmarks.compare import (_leading_number, _override_limit,
-                                classify, compare_records,
-                                load_overrides)
 
 ROWS = [
     "engine_throughput/steady,12.41 req/s,0.97s for 12 reqs "
@@ -107,115 +104,3 @@ class TestWriteMerge:
             f.write('{"schema_version": 0, "suite": "unit"}')
         with pytest.raises(ValueError, match="schema_version"):
             write_bench_json(path, "unit", ROWS[:1], bench="a")
-
-
-def _rec(*rows_by_bench):
-    """Build a minimal valid record from (bench, row) pairs."""
-    return bench_record("serving",
-                        [parse_row(r, bench=b) for b, r in rows_by_bench])
-
-
-class TestCompare:
-    """`benchmarks/compare.py`: the trajectory diff CI runs between a
-    run's BENCH_<suite>.json and the previous artifact."""
-
-    def test_leading_number_and_classify(self):
-        assert _leading_number("12.41 req/s") == 12.41
-        assert _leading_number("ttft p50 0.123s") == 0.123
-        assert _leading_number("bit-exact across kill") is None
-        assert classify("engine_throughput/stream", "12.4 req/s") == \
-            ("higher", "time")
-        assert classify("engine_throughput/latency", "p50 0.1s") == \
-            ("lower", "time")
-        assert classify("serving_cache/quanta", "prefill 4 + decode 9") \
-            == ("lower", "count")
-        assert classify("serving_cache/bytes", "paged 34.8 KB")[0] == \
-            "lower"
-        assert classify("fleet_smoke/scaling", "2.99x speedup")[0] == \
-            "higher"
-
-    def test_improvement_and_within_threshold_pass(self):
-        base = _rec(("a", "x/tput,10.0 req/s"), ("a", "x/quanta,20 quanta"))
-        cur = _rec(("a", "x/tput,12.0 req/s"), ("a", "x/quanta,20 quanta"))
-        report, regressions = compare_records(base, cur, 0.5, 0.05)
-        assert not regressions
-        assert any("ok" in line for line in report)
-
-    def test_counter_regression_gates_tight(self):
-        base = _rec(("a", "x/quanta,20 quanta"))
-        cur = _rec(("a", "x/quanta,23 quanta"))   # +15% > 5%
-        _, regressions = compare_records(base, cur, 0.5, 0.05)
-        assert len(regressions) == 1 and "REGRESS" in regressions[0]
-
-    def test_time_metric_tolerates_runner_noise(self):
-        base = _rec(("a", "x/tput,10.0 req/s"))
-        cur = _rec(("a", "x/tput,8.0 req/s"))     # -20% < 50%
-        report, regressions = compare_records(base, cur, 0.5, 0.05)
-        assert not regressions and any("~" in line for line in report)
-        cur = _rec(("a", "x/tput,3.0 req/s"))     # -70% > 50%
-        _, regressions = compare_records(base, cur, 0.5, 0.05)
-        assert len(regressions) == 1
-
-    def test_new_gone_and_text_metrics_never_gate(self):
-        base = _rec(("a", "x/old,5 quanta"), ("a", "x/note,all good"))
-        cur = _rec(("a", "x/new,7 quanta"), ("a", "x/note,still good"))
-        report, regressions = compare_records(base, cur, 0.5, 0.05)
-        assert not regressions
-        joined = "\n".join(report)
-        assert "NEW" in joined and "GONE" in joined and "text" in joined
-
-    def test_zero_baseline_handled(self):
-        base = _rec(("a", "x/launches,0 launches"))
-        cur = _rec(("a", "x/launches,2 launches"))
-        _, regressions = compare_records(base, cur, 0.5, 0.05)
-        assert len(regressions) == 1   # 0 -> nonzero is inf regression
-
-
-class TestCompareOverrides:
-    """Per-metric threshold overrides (`--config`): globs against
-    ``bench/name`` then the bare name; first match wins; defaults
-    apply when absent or unmatched."""
-
-    def test_load_overrides_validation(self):
-        ovs = load_overrides({"overrides": [
-            {"pattern": "a/*", "threshold": 0.2},
-            {"pattern": "*quanta*", "threshold": 0},
-        ]})
-        assert ovs == [("a/*", 0.2), ("*quanta*", 0.0)]
-        assert load_overrides({}) == []
-        with pytest.raises(ValueError, match="pattern"):
-            load_overrides({"overrides": [{"threshold": 0.1}]})
-        with pytest.raises(ValueError, match=">= 0"):
-            load_overrides({"overrides": [
-                {"pattern": "x", "threshold": -0.1}]})
-
-    def test_override_matching_order(self):
-        ovs = [("a/x*", 0.1), ("x/*", 0.2)]
-        assert _override_limit(ovs, "a", "x/quanta") == 0.1
-        # second pattern matches the bare name, not bench/name
-        assert _override_limit(ovs, "b", "x/quanta") == 0.2
-        assert _override_limit(ovs, "b", "y/quanta") is None
-
-    def test_override_loosens_tight_counter_gate(self):
-        base = _rec(("a", "x/quanta,20 quanta"))
-        cur = _rec(("a", "x/quanta,23 quanta"))   # +15% > default 5%
-        _, regress = compare_records(base, cur, 0.5, 0.05,
-                                     overrides=[("a/x/quanta", 0.2)])
-        assert not regress
-        report, _ = compare_records(base, cur, 0.5, 0.05,
-                                    overrides=[("a/x/quanta", 0.2)])
-        assert any("override" in line for line in report)
-
-    def test_override_tightens_loose_time_gate(self):
-        base = _rec(("a", "x/tput,10.0 req/s"))
-        cur = _rec(("a", "x/tput,9.0 req/s"))     # -10% < default 50%
-        _, regress = compare_records(base, cur, 0.5, 0.05,
-                                     overrides=[("*tput*", 0.0)])
-        assert len(regress) == 1 and "override" in regress[0]
-
-    def test_unmatched_pattern_keeps_defaults(self):
-        base = _rec(("a", "x/quanta,20 quanta"))
-        cur = _rec(("a", "x/quanta,23 quanta"))
-        _, regress = compare_records(base, cur, 0.5, 0.05,
-                                     overrides=[("elsewhere/*", 0.9)])
-        assert len(regress) == 1 and "count threshold" in regress[0]

@@ -1,5 +1,8 @@
 """Per-kernel tests: Pallas (interpret=True) vs pure-jnp oracles,
 swept over shapes and dtypes per the deliverable contract."""
+import functools
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -155,3 +158,72 @@ def test_quantized_matmul_dispatch_gqa_and_leading_dims():
     v = jax.random.normal(jax.random.PRNGKey(12), (1, 2, 16, 32))
     out = ops.attention(q, k, v)
     assert out.shape == q.shape
+
+
+# ------------------------------------------------------------ kernel names
+
+def _kernel_cases():
+    from repro.kernels.flash_decode import flash_decode, flash_decode_paged
+    from repro.kernels.flash_prefill import (flash_prefill_paged,
+                                             flash_prefill_paged_q8)
+    S = jax.ShapeDtypeStruct
+    bf, i8, u8 = jnp.bfloat16, jnp.int8, jnp.uint8
+    f32, i32, f16 = jnp.float32, jnp.int32, jnp.float16
+    m, k, n = 128, 256, 128
+    t, h, g, d, bs, nb, mb = 16, 2, 2, 64, 16, 8, 4
+    pool = (S((nb, h, bs, d), bf),) * 2
+    return {
+        "q8_matmul": (q8_matmul, (S((m, k), bf), S((n, k), i8),
+                                  S((n, k // 32), f32))),
+        "q8_matmul_w8a8": (q8_matmul_w8a8, (
+            S((m, k), i8), S((m, k // 32), f32), S((n, k), i8),
+            S((n, k // 32), f32))),
+        "q3k_matmul": (q3k_matmul, (
+            S((m, k), bf), S((n, k // 4), u8), S((n, k // 8), u8),
+            S((n, k // 16), u8), S((n, k // 256), f32))),
+        "q4_matmul": (q4_matmul, (S((m, k), bf), S((n, k // 2), u8),
+                                  S((n, k // 32), f32))),
+        "flash_attention": (flash_attention, (S((1, 2, 128, 64), bf),) * 3),
+        "flash_decode": (flash_decode, (
+            S((2, h, g, d), bf), S((2, h, 128, d), bf),
+            S((2, h, 128, d), bf), S((2,), i32))),
+        "flash_decode_paged": (flash_decode_paged, (
+            S((2, h, g, d), bf), *pool, S((2, mb), i32), S((2,), i32))),
+        "flash_prefill_paged": (flash_prefill_paged, (
+            S((t, h, g, d), bf), S((t, h, d), bf), S((t, h, d), bf), *pool,
+            S((mb,), i32), S((), i32))),
+        "flash_prefill_paged_q8": (flash_prefill_paged_q8, (
+            S((t, h, g, d), bf), S((t, h, d), bf), S((t, h, d), bf),
+            S((nb, h, bs, d), i8), S((nb, h, bs, d), i8),
+            S((nb, h, bs, d // 32), f16), S((nb, h, bs, d // 32), f16),
+            S((mb,), i32), S((), i32))),
+    }
+
+
+KERNELS = ["q8_matmul", "q8_matmul_w8a8", "q3k_matmul", "q4_matmul",
+           "flash_attention", "flash_decode", "flash_decode_paged",
+           "flash_prefill_paged", "flash_prefill_paged_q8"]
+
+
+@pytest.mark.parametrize("kernel", KERNELS)
+def test_pallas_call_is_named_after_its_kernel(kernel):
+    """Every kernel's ``pallas_call`` carries its function's name: the
+    custom call's name in a compiled program and a device trace."""
+    fn, args = _kernel_cases()[kernel]
+    jx = jax.make_jaxpr(functools.partial(fn, interpret=True))(*args)
+    assert [e.params["name"] for e in jx.jaxpr.eqns
+            if e.primitive.name == "pallas_call"] == [kernel]
+
+
+def test_every_pallas_call_has_a_name():
+    """No ``pallas_call`` in the kernels package is left unnamed (so a
+    new kernel joins ``KERNELS``)."""
+    import pathlib
+    import repro.kernels
+    root = pathlib.Path(repro.kernels.__file__).parent
+    calls = names = 0
+    for path in root.glob("*.py"):
+        src = path.read_text()
+        calls += src.count("pl.pallas_call(")
+        names += len(re.findall(r'^\s+name="\w+",$', src, re.M))
+    assert calls == names == len(KERNELS)

@@ -11,6 +11,7 @@ only one process may load the TPU library, and under pytest-xdist every
 worker imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,8 +21,9 @@ from jax.sharding import SingleDeviceSharding
 from repro.core import quant
 from repro.kernels import ops
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.flash_decode import flash_decode_paged
-from repro.kernels.flash_prefill import flash_prefill_paged
+from repro.kernels.flash_decode import flash_decode, flash_decode_paged
+from repro.kernels.flash_prefill import (flash_prefill_paged,
+                                         flash_prefill_paged_q8)
 
 
 @pytest.fixture(scope="module")
@@ -123,3 +125,63 @@ def test_flash_decode_paged_compiles(one_chip):
         _spec(one_chip, (nb, h, bs, d), jnp.bfloat16),
         _spec(one_chip, (b, mb), jnp.int32), _spec(one_chip, (b,), jnp.int32))
     assert "tpu_custom_call" in text
+
+
+def _named_kernels(sh):
+    """(fn, args) per Pallas kernel that compiles for v5e: small shapes
+    of each kernel's main path."""
+    bf16 = jnp.bfloat16
+
+    def matmul(fmt, m, k, n):
+        return _matmul, (_spec(sh, (m, k), bf16), _weight(sh, fmt, n, k))
+
+    t, h, g, d, bs, nb, mb = 128, 8, 4, 128, 16, 64, 32
+    return {
+        "q8_matmul": matmul("q8_0", 256, 320, 320),
+        "q3k_matmul": matmul("q3_k", 256, 768, 768),
+        "q4_matmul": matmul("q4_0", 256, 320, 320),
+        "flash_attention": (
+            lambda q, k, v: flash_attention(q, k, v, causal=False),
+            tuple(_spec(sh, (2, 8, 256, 64), bf16) for _ in range(3))),
+        "flash_decode": (flash_decode, (
+            _spec(sh, (8, h, g, d), bf16), _spec(sh, (8, h, 512, d), bf16),
+            _spec(sh, (8, h, 512, d), bf16), _spec(sh, (8,), jnp.int32))),
+        "flash_decode_paged": (flash_decode_paged, (
+            _spec(sh, (8, h, g, d), bf16), _spec(sh, (nb, h, bs, d), bf16),
+            _spec(sh, (nb, h, bs, d), bf16), _spec(sh, (8, mb), jnp.int32),
+            _spec(sh, (8,), jnp.int32))),
+        "flash_prefill_paged": (flash_prefill_paged, (
+            _spec(sh, (t, h, g, d), bf16), _spec(sh, (t, h, d), bf16),
+            _spec(sh, (t, h, d), bf16), _spec(sh, (nb, h, bs, d), bf16),
+            _spec(sh, (nb, h, bs, d), bf16), _spec(sh, (mb,), jnp.int32),
+            _spec(sh, (), jnp.int32))),
+    }
+
+
+@pytest.mark.parametrize("kernel", [
+    "q8_matmul", "q3k_matmul", "q4_matmul", "flash_attention",
+    "flash_decode", "flash_decode_paged", "flash_prefill_paged"])
+def test_compiled_kernel_carries_its_name(one_chip, kernel):
+    """A kernel's custom call is named after its function in the
+    compiled program: the op name a device trace shows for it."""
+    fn, args = _named_kernels(one_chip)[kernel]
+    text = _compiled_text(fn, *args)
+    assert re.search(rf"^\s*(ROOT )?%{kernel}(\.\d+)? = .* custom-call\(.*"
+                     r"custom_call_target=\"tpu_custom_call\"", text, re.M)
+
+
+def test_flash_prefill_paged_q8_lowers_with_its_name(one_chip):
+    """The Q8_0 prefill kernel does not compile for v5e yet (a reshape
+    Mosaic refuses); its lowered custom call already names it."""
+    t, h, g, d, bs, nb, mb = 128, 8, 4, 128, 16, 64, 32
+    text = jax.jit(flash_prefill_paged_q8).lower(
+        _spec(one_chip, (t, h, g, d), jnp.bfloat16),
+        _spec(one_chip, (t, h, d), jnp.bfloat16),
+        _spec(one_chip, (t, h, d), jnp.bfloat16),
+        _spec(one_chip, (nb, h, bs, d), jnp.int8),
+        _spec(one_chip, (nb, h, bs, d), jnp.int8),
+        _spec(one_chip, (nb, h, bs, d // 32), jnp.float16),
+        _spec(one_chip, (nb, h, bs, d // 32), jnp.float16),
+        _spec(one_chip, (mb,), jnp.int32),
+        _spec(one_chip, (), jnp.int32)).as_text()
+    assert 'kernel_name = "flash_prefill_paged_q8"' in text
