@@ -60,7 +60,9 @@ NONE_POLICY = OffloadPolicy(name="none", default="bf16")
 
 # stable-diffusion.cpp executes convs as im2col + F16 mul_mat and does
 # NOT quantize conv weights; attention act-act mul_mats run in F32.
-# This is what produces Table I's large F16/F32 residue.
+# This is what produces Table I's large F16/F32 residue.  Here the f16
+# convs run as native TPU convolutions (``unet.apply_conv``), still
+# counted as that im2col mul_mat.
 Q8_0_POLICY = OffloadPolicy(
     name="q8_0",
     default="q8_0",

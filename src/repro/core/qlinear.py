@@ -115,15 +115,16 @@ def quantize_params(params: Any, policy: OffloadPolicy) -> Any:
         params, is_leaf=lambda x: isinstance(x, Linear))
 
 
-def _qleaf(x):
+def is_packed(x) -> bool:
+    """Whether ``x`` is a packed quantized weight (Q8_0, Q4_0, Q3_K)."""
     return isinstance(x, _QTYPES)
 
 
 def param_bytes(params: Any) -> int:
     """Total parameter storage bytes (quantized tensors count packed)."""
     total = 0
-    for leaf in jax.tree_util.tree_leaves(params, is_leaf=_qleaf):
-        if _qleaf(leaf):
+    for leaf in jax.tree_util.tree_leaves(params, is_leaf=is_packed):
+        if is_packed(leaf):
             total += leaf.nbytes()
         elif hasattr(leaf, "dtype"):
             total += leaf.size * leaf.dtype.itemsize
@@ -133,8 +134,8 @@ def param_bytes(params: Any) -> int:
 def param_count(params: Any) -> int:
     """Logical parameter count (quantized tensors count logical size)."""
     total = 0
-    for leaf in jax.tree_util.tree_leaves(params, is_leaf=_qleaf):
-        if _qleaf(leaf):
+    for leaf in jax.tree_util.tree_leaves(params, is_leaf=is_packed):
+        if is_packed(leaf):
             total += int(jnp.prod(jnp.array(leaf.shape)))
         elif hasattr(leaf, "size"):
             total += leaf.size

@@ -1,10 +1,13 @@
 """SD v1.5 / SD-Turbo U-Net in JAX.
 
-Faithful to stable-diffusion.cpp's execution structure: **convolutions
-are im2col + mul_mat** (exactly how GGML lowers them), so every conv is
-a role-tagged linear and participates in the paper's dot-product
-accounting.  Attention blocks are spatial transformers with cross
-attention to the CLIP text states.
+Faithful to stable-diffusion.cpp's structure: every convolution is a
+role-tagged linear whose weight is stored as GGML's im2col ``mul_mat``
+operand ``(out, in*k*k)`` and recorded as that product, so it takes
+part in the paper's dot-product accounting.  On the device it runs as a
+native convolution (1x1 ones as a plain matmul), which reads each input
+once instead of building a patch tensor k*k times its size; only a
+packed quantized conv weight takes the im2col route.  Attention blocks
+are spatial transformers with cross attention to the CLIP text states.
 
 Full-size config matches SD v1.5 (SD-Turbo shares the architecture);
 tests run a reduced config.
@@ -17,7 +20,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.core.qlinear import Linear, apply_linear, init_linear
+from repro.core.qlinear import (Linear, apply_linear, init_linear,
+                                is_packed, record_matmul)
 from repro.kernels import ops
 from repro.models import layers as L
 
@@ -51,7 +55,9 @@ TINY_UNET = UNetConfig(model_channels=32, channel_mult=(1, 2),
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class Conv:
-    """im2col conv: a Linear over patches. Kernel size is static aux."""
+    """A conv as a Linear over im2col patches: the weight is ``(out,
+    in*k*k)``, features ordered ``(in, kh, kw)``. Kernel size is static
+    aux."""
     lin: Linear
     k: int = 3
 
@@ -72,19 +78,39 @@ def init_conv(key, in_ch: int, out_ch: int, k: int = 3, *,
 
 
 def apply_conv(p: Conv, x: jax.Array, stride: int = 1) -> jax.Array:
-    """x: (B, H, W, C) -> (B, H', W', out_ch) via im2col + mul_mat.
+    """x: (B, H, W, C) -> (B, H', W', out_ch), ``same`` padding.
 
-    The whole convolution (patches, matmul, bias) runs under the
-    ``conv`` named scope, whatever implements it, so a device trace
-    measures every convolution against the same work."""
-    k = p.k
+    A dense weight runs as one native convolution (a 1x1 one as a
+    matmul over ``x``) with the dense ``apply_linear``'s numerics: both
+    operands in the weight's dtype, f32 accumulation, the result in
+    ``x``'s dtype. A packed quantized weight takes the im2col route:
+    the patch tensor GGML builds, then the quantized matmul. Either way
+    the recorder sees the im2col product (m = B*H'*W', n = out_ch,
+    k = C*k*k), and the whole convolution runs under the ``conv`` named
+    scope, so a device trace measures every convolution against the
+    same work."""
+    k, lin = p.k, p.lin
     pad = (k - 1) // 2
     with jax.named_scope("conv"):
-        patches = jax.lax.conv_general_dilated_patches(
-            x, (k, k), (stride, stride), ((pad, pad), (pad, pad)),
-            dimension_numbers=("NHWC", "HWIO", "NHWC"))
-        # patches: (B, H', W', C*k*k) — the im2col buffer GGML builds.
-        return apply_linear(p.lin, patches)
+        if k == 1:
+            return apply_linear(lin, x[:, ::stride, ::stride])
+        if is_packed(lin.w):
+            patches = jax.lax.conv_general_dilated_patches(
+                x, (k, k), (stride, stride), ((pad, pad), (pad, pad)),
+                dimension_numbers=("NHWC", "HWIO", "NHWC"))
+            return apply_linear(lin, patches)
+        w = lin.w
+        # (O, C*k*k) -> (O, C, k, k): the patches' feature order.
+        y = jax.lax.conv_general_dilated(
+            x.astype(w.dtype), w.reshape(w.shape[0], -1, k, k),
+            (stride, stride), ((pad, pad), (pad, pad)),
+            dimension_numbers=("NHWC", "OIHW", "NHWC"),
+            preferred_element_type=jnp.float32).astype(x.dtype)
+        b, h, wd, n = y.shape
+        record_matmul("linear", lin.role, b * h * wd, n, w.shape[1])
+        if lin.b is not None:
+            y = y + lin.b.astype(y.dtype)
+        return y
 
 
 # ------------------------------------------------------------ groupnorm

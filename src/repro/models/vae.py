@@ -1,4 +1,7 @@
-"""SD VAE decoder (latent -> image), GGML-style im2col convs."""
+"""SD VAE decoder (latent -> image).
+
+Its convolutions are ``unet.apply_conv``'s: native convolutions on the
+device, counted as GGML's im2col ``mul_mat``."""
 from __future__ import annotations
 
 import dataclasses
