@@ -16,12 +16,15 @@ import dataclasses
 from typing import Mapping
 
 # Roles a weight tensor can play.  Any matmul weight in the framework is
-# tagged with one of these when created.
+# tagged with one of these when created.  ``proj`` is a dense projection
+# outside attention and MLP that a model file quantizes like them
+# (SDXL's linear proj_in/proj_out, OpenCLIP's text_projection);
+# ``proj_misc`` is small glue kept in high precision.
 ROLES = (
     "attn_qkv", "attn_out", "mlp_up", "mlp_gate", "mlp_down",
     "expert_up", "expert_gate", "expert_down", "router",
     "ssm_in", "ssm_out", "ssm_x",
-    "embed", "lm_head", "conv", "time_embed", "proj_misc",
+    "embed", "lm_head", "conv", "time_embed", "proj_misc", "proj",
 )
 
 # Formats understood by repro.core.quant.quantize().

@@ -87,6 +87,14 @@ class SDConfig:
     latent_hw: int = 64          # 512x512 image -> 64x64 latent
     text_len: int = 77
     steps: int = 1               # SD-Turbo single step
+    # Second text encoder (SDXL's OpenCLIP ViT-bigG), on the same
+    # tokens: its states join the context after the first encoder's,
+    # and its pooled state feeds the UNet's added embedding.
+    clip_g: Any = None
+    # Both encoders' output layer, stable-diffusion.cpp's --clip-skip:
+    # 1 the last layer with the final layer norm, 2 the penultimate
+    # layer without it (SDXL).
+    clip_skip: int = 1
 
     def clip_cfg(self):
         return self.clip or clip_mod.clip_config()
@@ -96,15 +104,33 @@ SD_TURBO = SDConfig()
 TINY_SD = SDConfig(name="tiny-sd", unet=unet_mod.TINY_UNET,
                    vae=vae_mod.TINY_VAE, clip=clip_mod.TINY_CLIP,
                    latent_hw=8, steps=1)
+# SDXL base 1.0 at 1024x1024: CLIP ViT-L/14 and OpenCLIP ViT-bigG/14.
+SDXL = SDConfig(name="sdxl", unet=unet_mod.SDXL_UNET,
+                vae=vae_mod.SDXL_VAE, clip=clip_mod.clip_config(),
+                clip_g=clip_mod.clip_config(d_model=1280, layers=32,
+                                            heads=20),
+                latent_hw=128, clip_skip=2)
+TINY_XL = SDConfig(name="tiny-xl", unet=unet_mod.TINY_XL_UNET,
+                   vae=dataclasses.replace(vae_mod.TINY_VAE,
+                                           scale_factor=0.13025),
+                   clip=clip_mod.clip_config(d_model=16, layers=3, heads=2,
+                                             vocab=512),
+                   clip_g=clip_mod.clip_config(d_model=32, layers=3,
+                                               heads=2, vocab=512),
+                   latent_hw=8, clip_skip=2)
 
 
 def init_pipeline(key: jax.Array, cfg: SDConfig) -> dict:
     ks = jax.random.split(key, 3)
-    return {
+    p = {
         "clip": clip_mod.init_clip(ks[0], cfg.clip_cfg()),
         "unet": unet_mod.init_unet(ks[1], cfg.unet),
         "vae": vae_mod.init_vae_decoder(ks[2], cfg.vae),
     }
+    if cfg.clip_g is not None:
+        p["clip_g"] = clip_mod.init_clip(jax.random.fold_in(key, 3),
+                                         cfg.clip_g, projection=True)
+    return p
 
 
 def quantize_pipeline(params: dict, policy: OffloadPolicy) -> dict:
@@ -129,36 +155,57 @@ def steps_bucket(steps: int) -> int:
     return b
 
 
-def _encode(params, clip_cfg, tokens, neg_tokens, use_cfg: bool):
-    """CLIP on the prompts (and the negative prompts under CFG), under
-    the ``clip`` scope."""
+def _encode_one(params, cfg: SDConfig, tokens):
+    """The UNet's text conditioning of one batch of prompts: the context
+    ``(B, S, ctx_dim)``, and with a second encoder its pooled state."""
+    ctx = clip_mod.clip_encode(params["clip"], cfg.clip_cfg(), tokens,
+                               cfg.clip_skip)
+    if cfg.clip_g is None:
+        return ctx, None
+    with jax.named_scope("clip_g"):
+        ctx_g, pooled = clip_mod.clip_encode(params["clip_g"], cfg.clip_g,
+                                             tokens, cfg.clip_skip,
+                                             pooled=True)
+    return jnp.concatenate([ctx, ctx_g], -1), pooled
+
+
+def _encode(params, cfg: SDConfig, tokens, neg_tokens, use_cfg: bool):
+    """The conditioning of the prompts (and of the negative prompts
+    under CFG), under the ``clip`` scope."""
     with jax.named_scope("clip"):
-        ctx = clip_mod.clip_encode(params["clip"], clip_cfg, tokens)
-        ctx_u = (clip_mod.clip_encode(params["clip"], clip_cfg, neg_tokens)
-                 if use_cfg else None)
-    return ctx, ctx_u
+        cond = _encode_one(params, cfg, tokens)
+        cond_u = (_encode_one(params, cfg, neg_tokens) if use_cfg
+                  else None)
+    return cond, cond_u
 
 
-def _eps(params, cfg: SDConfig, xm, tb, ctx, ctx_u, g):
+def _eps(params, cfg: SDConfig, xm, tb, cond, cond_u, g):
     """The noise prediction of one solver step: one UNet evaluation per
     guidance branch, each under the ``unet`` scope."""
+    b, hw = xm.shape[0], xm.shape[1]
+    side = hw * 2 ** (len(cfg.vae.channel_mult) - 1)
+
     def unet(c):
+        ctx, pooled = c
+        added = {} if pooled is None else dict(
+            text_embeds=pooled,
+            time_ids=unet_mod.image_size_ids(b, side))
         with jax.named_scope("unet"):
             return unet_mod.apply_unet(params["unet"], cfg.unet,
                                        xm.astype(jnp.bfloat16), tb,
-                                       c).astype(jnp.float32)
+                                       ctx, **added).astype(jnp.float32)
 
-    eps = unet(ctx)
-    if ctx_u is not None:
-        eps_u = unet(ctx_u)
+    eps = unet(cond)
+    if cond_u is not None:
+        eps_u = unet(cond_u)
         eps = eps_u + g * (eps - eps_u)
     return eps
 
 
 def _decode(params, cfg: SDConfig, x0):
     with jax.named_scope("vae"):
-        return vae_mod.apply_vae_decoder(params["vae"], cfg.vae,
-                                         x0.astype(jnp.bfloat16))
+        return vae_mod.decode(params["vae"], cfg.vae,
+                              x0.astype(jnp.bfloat16))
 
 
 def build_denoise(cfg: SDConfig, sampler_name: str, use_cfg: bool, *,
@@ -175,11 +222,10 @@ def build_denoise(cfg: SDConfig, sampler_name: str, use_cfg: bool, *,
     """
     sampler = samplers_mod.get_sampler(sampler_name)
     sched = sched_mod.NoiseSchedule()
-    clip_cfg = cfg.clip_cfg()
 
     def fn(params, tokens, neg_tokens, gscale, noise, plan):
         b = tokens.shape[0]
-        ctx, ctx_u = _encode(params, clip_cfg, tokens, neg_tokens, use_cfg)
+        ctx, ctx_u = _encode(params, cfg, tokens, neg_tokens, use_cfg)
         x = sampler.init_latent(noise.astype(jnp.float32), plan)
         g = gscale[:, None, None, None]
 
@@ -200,11 +246,11 @@ def build_denoise(cfg: SDConfig, sampler_name: str, use_cfg: bool, *,
 
 def build_encode(cfg: SDConfig, use_cfg: bool) -> Callable:
     """Prompt-encoding half of the segmented (preview-streaming) path:
-    ``fn(params, tokens, neg_tokens) -> (ctx, ctx_uncond|None)``."""
-    clip_cfg = cfg.clip_cfg()
+    ``fn(params, tokens, neg_tokens) -> (cond, cond_uncond|None)``, each
+    ``(context, pooled state|None)``."""
 
     def fn(params, tokens, neg_tokens):
-        return _encode(params, clip_cfg, tokens, neg_tokens, use_cfg)
+        return _encode(params, cfg, tokens, neg_tokens, use_cfg)
     return fn
 
 
@@ -461,7 +507,9 @@ class DiffusionEngine(ev.EventStreamMixin):
         ``engine.step`` holds ``engine.admit`` (pop and events),
         ``engine.pack`` (request rows to device arrays),
         ``engine.launch`` (each jitted call, with the batch's ``rids``,
-        ``rows``, ``bucket``, ``steps``, ``sampler`` and ``cfg``) and
+        ``rows``, ``bucket``, ``steps``, ``sampler``, ``cfg``,
+        ``latent_hw`` and ``vae_chunk``, the images per VAE decode
+        call) and
         ``engine.retire`` (row slices and ``Finished``)."""
         with span("engine.step"):
             return self._step()
@@ -574,6 +622,10 @@ class DiffusionEngine(ev.EventStreamMixin):
                     engine="diffusion", clock=self.bus.clock, rids=rids,
                     weight_quant=self.weight_quant, **args)
 
+    def _vae_chunk(self, hw: int) -> int:
+        """Images per VAE decode call of a batch at latent size ``hw``."""
+        return vae_mod.images_per_call(self.cfg.vae, self.max_batch, hw)
+
     def _obs_sched(self) -> None:
         if self.metrics is None:
             return
@@ -651,7 +703,8 @@ class DiffusionEngine(ev.EventStreamMixin):
         t0, tr0 = self.bus.clock(), self.traces
         with self._launch("fused", [r.rid for r in reqs], rows=len(reqs),
                           bucket=sbucket, steps=steps, sampler=sampler_name,
-                          cfg=use_cfg):
+                          cfg=use_cfg, latent_hw=hw,
+                          vae_chunk=self._vae_chunk(hw)):
             imgs = fn(self.params, toks, negs, scales, noises, plan)
         self._observe(("diff", self.cfg.name, "fused", sampler_name,
                        sbucket, hw, use_cfg, self.max_batch,
@@ -725,7 +778,8 @@ class DiffusionEngine(ev.EventStreamMixin):
                                                           sampler_name))
             t0, tr0 = self.bus.clock(), self.traces
             with self._launch("vae", [r.rid for _row, r in at_stride],
-                              rows=len(at_stride), preview=True):
+                              rows=len(at_stride), preview=True,
+                              latent_hw=hw, vae_chunk=self._vae_chunk(hw)):
                 pv_imgs = dec(self.params, st["x"])
             self._observe(("diff", self.cfg.name, "vae", hw,
                            self.max_batch, self.weight_quant), t0, tr0,
@@ -749,7 +803,8 @@ class DiffusionEngine(ev.EventStreamMixin):
                                                           sampler_name))
             t0, tr0 = self.bus.clock(), self.traces
             with self._launch("vae", [r.rid for _row, r in live],
-                              rows=len(live)):
+                              rows=len(live), latent_hw=hw,
+                              vae_chunk=self._vae_chunk(hw)):
                 imgs = dec(self.params, st["x"])
             self._observe(("diff", self.cfg.name, "vae", hw,
                            self.max_batch, self.weight_quant), t0, tr0,

@@ -1,4 +1,4 @@
-"""SD v1.5 / SD-Turbo U-Net in JAX.
+"""SD v1.5 / SD-Turbo / SDXL U-Net in JAX.
 
 Faithful to stable-diffusion.cpp's structure: every convolution is a
 role-tagged linear whose weight is stored as GGML's im2col ``mul_mat``
@@ -10,7 +10,11 @@ packed quantized conv weight takes the im2col route.  Attention blocks
 are spatial transformers with cross attention to the CLIP text states.
 
 Full-size config matches SD v1.5 (SD-Turbo shares the architecture);
-tests run a reduced config.
+SDXL's differences are config fields named for its ``unet/config.json``
+keys: per-level transformer depth (a stack deeper than one block runs as
+a ``lax.scan`` over stacked block weights), a fixed channel count per
+head, linear ``proj_in``/``proj_out``, and the added embedding of the
+pooled text state and the image-size ids.  Tests run reduced configs.
 """
 from __future__ import annotations
 
@@ -35,19 +39,56 @@ class UNetConfig:
     num_res_blocks: int = 2
     attention_levels: tuple = (0, 1, 2)   # levels with spatial transformer
     num_heads: int = 8
-    context_dim: int = 768                # CLIP hidden size
+    context_dim: int = 768                # text states' width
     time_dim_mult: int = 4
     groups: int = 32
+    # Spatial transformer blocks per attention level (int: every level);
+    # the mid block takes the last level's.
+    transformer_layers_per_block: int | tuple = 1
+    # Channels per attention head (SDXL: 64); None keeps ``num_heads``
+    # at every width.  Diffusers' key of this name holds the head
+    # counts, 5/10/20 for SDXL: 64 channels each.
+    attention_head_dim: int | None = None
+    use_linear_projection: bool = False   # linear proj_in/out, not 1x1 conv
+    # Added embedding (SDXL): sinusoid width of each image-size id, and
+    # the input width of its MLP (pooled text state + 6 ids' sinusoids).
+    addition_time_embed_dim: int = 0
+    projection_class_embeddings_input_dim: int = 0
 
     @property
     def time_dim(self) -> int:
         return self.model_channels * self.time_dim_mult
+
+    def depth(self, lvl: int) -> int:
+        d = self.transformer_layers_per_block
+        return d if isinstance(d, int) else d[lvl]
+
+    def heads(self, ch: int) -> int:
+        if self.attention_head_dim is None:
+            return self.num_heads
+        return ch // self.attention_head_dim
 
 
 SD15_UNET = UNetConfig()
 TINY_UNET = UNetConfig(model_channels=32, channel_mult=(1, 2),
                        num_res_blocks=1, attention_levels=(0, 1),
                        num_heads=2, context_dim=64, groups=8)
+# SDXL base 1.0 (stabilityai/stable-diffusion-xl-base-1.0 unet/config.json).
+SDXL_UNET = UNetConfig(channel_mult=(1, 2, 4), attention_levels=(1, 2),
+                       context_dim=2048,
+                       transformer_layers_per_block=(1, 2, 10),
+                       attention_head_dim=64, use_linear_projection=True,
+                       addition_time_embed_dim=256,
+                       projection_class_embeddings_input_dim=2816)
+# SDXL's mechanisms at the CPU tests' size: no attention at level 0,
+# depth 1 at level 1, a stacked depth-2 stack at level 2 and mid.
+TINY_XL_UNET = UNetConfig(model_channels=16, channel_mult=(1, 2, 2),
+                          num_res_blocks=1, attention_levels=(1, 2),
+                          context_dim=48, groups=8,
+                          transformer_layers_per_block=(0, 1, 2),
+                          attention_head_dim=8, use_linear_projection=True,
+                          addition_time_embed_dim=8,
+                          projection_class_embeddings_input_dim=32 + 6 * 8)
 
 
 # ---------------------------------------------------------------- conv
@@ -159,27 +200,47 @@ def apply_resblock(p: dict, x: jax.Array, temb: jax.Array,
 
 # ------------------------------------------- spatial transformer block
 
-def init_spatial_transformer(key, ch: int, cfg: UNetConfig) -> dict:
-    ks = jax.random.split(key, 12)
-    inner = ch
+def _init_block(ks, ch: int, ctx_dim: int) -> dict:
+    """One transformer block (self-attention, cross-attention, GEGLU
+    feed-forward) from ten keys."""
     return {
-        "norm": init_groupnorm(ch),
-        "proj_in": init_conv(ks[0], ch, inner, k=1),
-        "ln1": L.init_layernorm(inner),
-        "q1": init_linear(ks[1], inner, inner, role="attn_qkv"),
-        "k1": init_linear(ks[2], inner, inner, role="attn_qkv"),
-        "v1": init_linear(ks[3], inner, inner, role="attn_qkv"),
-        "o1": init_linear(ks[4], inner, inner, role="attn_out"),
-        "ln2": L.init_layernorm(inner),
-        "q2": init_linear(ks[5], inner, inner, role="attn_qkv"),
-        "k2": init_linear(ks[6], cfg.context_dim, inner, role="attn_qkv"),
-        "v2": init_linear(ks[7], cfg.context_dim, inner, role="attn_qkv"),
-        "o2": init_linear(ks[8], inner, inner, role="attn_out"),
-        "ln3": L.init_layernorm(inner),
-        "ff1": init_linear(ks[9], inner, inner * 8, role="mlp_up"),
-        "ff2": init_linear(ks[10], inner * 4, inner, role="mlp_down"),
-        "proj_out": init_conv(ks[11], inner, ch, k=1),
+        "ln1": L.init_layernorm(ch),
+        "q1": init_linear(ks[0], ch, ch, role="attn_qkv"),
+        "k1": init_linear(ks[1], ch, ch, role="attn_qkv"),
+        "v1": init_linear(ks[2], ch, ch, role="attn_qkv"),
+        "o1": init_linear(ks[3], ch, ch, role="attn_out"),
+        "ln2": L.init_layernorm(ch),
+        "q2": init_linear(ks[4], ch, ch, role="attn_qkv"),
+        "k2": init_linear(ks[5], ctx_dim, ch, role="attn_qkv"),
+        "v2": init_linear(ks[6], ctx_dim, ch, role="attn_qkv"),
+        "o2": init_linear(ks[7], ch, ch, role="attn_out"),
+        "ln3": L.init_layernorm(ch),
+        "ff1": init_linear(ks[8], ch, ch * 8, role="mlp_up"),
+        "ff2": init_linear(ks[9], ch * 4, ch, role="mlp_down"),
     }
+
+
+def init_spatial_transformer(key, ch: int, cfg: UNetConfig,
+                             depth: int = 1) -> dict:
+    """Group norm, ``proj_in``, ``depth`` blocks, ``proj_out``.  One
+    block's weights sit beside the projections; a deeper stack's are
+    stacked under ``blocks``, leading axis ``depth``."""
+    ks = jax.random.split(key, 12)
+    if cfg.use_linear_projection:
+        def proj(k):
+            return init_linear(k, ch, ch, role="proj", bias=True)
+    else:
+        def proj(k):
+            return init_conv(k, ch, ch, k=1)
+    p = {"norm": init_groupnorm(ch), "proj_in": proj(ks[0]),
+         "proj_out": proj(ks[11])}
+    if depth == 1:
+        p.update(_init_block(ks[1:11], ch, cfg.context_dim))
+    else:
+        p["blocks"] = jax.vmap(lambda k: _init_block(
+            jax.random.split(k, 10), ch, cfg.context_dim))(
+                jax.random.split(ks[1], depth))
+    return p
 
 
 def _mha(q_p, k_p, v_p, o_p, x, ctx, heads: int):
@@ -196,23 +257,41 @@ def _mha(q_p, k_p, v_p, o_p, x, ctx, heads: int):
     return apply_linear(o_p, out)
 
 
-def apply_spatial_transformer(p: dict, x: jax.Array, ctx: jax.Array,
-                              cfg: UNetConfig) -> jax.Array:
-    b, h, w, c = x.shape
-    res = x
-    xn = groupnorm(p["norm"], x, cfg.groups)
-    xn = apply_conv(p["proj_in"], xn).reshape(b, h * w, c)
+def _apply_block(p: dict, xn: jax.Array, ctx: jax.Array,
+                 heads: int) -> jax.Array:
     xn = xn + _mha(p["q1"], p["k1"], p["v1"], p["o1"],
                    L.layernorm(p["ln1"], xn), L.layernorm(p["ln1"], xn),
-                   cfg.num_heads)
+                   heads)
     xn = xn + _mha(p["q2"], p["k2"], p["v2"], p["o2"],
-                   L.layernorm(p["ln2"], xn), ctx, cfg.num_heads)
+                   L.layernorm(p["ln2"], xn), ctx, heads)
     # GEGLU feed-forward.
     hgl = apply_linear(p["ff1"], L.layernorm(p["ln3"], xn))
     hh, gate = jnp.split(hgl, 2, axis=-1)
-    xn = xn + apply_linear(p["ff2"], hh * jax.nn.gelu(gate))
-    xn = apply_conv(p["proj_out"], xn.reshape(b, h, w, c))
-    return res + xn
+    return xn + apply_linear(p["ff2"], hh * jax.nn.gelu(gate))
+
+
+def apply_spatial_transformer(p: dict, x: jax.Array, ctx: jax.Array,
+                              cfg: UNetConfig) -> jax.Array:
+    """Under the ``xfmr`` named scope."""
+    b, h, w, c = x.shape
+    heads = cfg.heads(c)
+    with jax.named_scope("xfmr"):
+        xn = groupnorm(p["norm"], x, cfg.groups)
+        if cfg.use_linear_projection:
+            xn = apply_linear(p["proj_in"], xn.reshape(b, h * w, c))
+        else:
+            xn = apply_conv(p["proj_in"], xn).reshape(b, h * w, c)
+        if "blocks" in p:
+            xn, _ = jax.lax.scan(
+                lambda t, bp: (_apply_block(bp, t, ctx, heads), None), xn,
+                p["blocks"])
+        else:
+            xn = _apply_block(p, xn, ctx, heads)
+        if cfg.use_linear_projection:
+            xn = apply_linear(p["proj_out"], xn).reshape(b, h, w, c)
+        else:
+            xn = apply_conv(p["proj_out"], xn.reshape(b, h, w, c))
+        return x + xn
 
 
 # ---------------------------------------------------------------- UNet
@@ -244,7 +323,8 @@ def init_unet(key, cfg: UNetConfig) -> dict:
             blk = {"res": init_resblock(next(ks), cur, out_ch,
                                         cfg.time_dim, cfg.groups)}
             if lvl in cfg.attention_levels:
-                blk["attn"] = init_spatial_transformer(next(ks), out_ch, cfg)
+                blk["attn"] = init_spatial_transformer(next(ks), out_ch, cfg,
+                                                       cfg.depth(lvl))
             downs.append(blk)
             cur = out_ch
             ch_stack.append(cur)
@@ -255,7 +335,8 @@ def init_unet(key, cfg: UNetConfig) -> dict:
 
     p["mid"] = {
         "res1": init_resblock(next(ks), cur, cur, cfg.time_dim, cfg.groups),
-        "attn": init_spatial_transformer(next(ks), cur, cfg),
+        "attn": init_spatial_transformer(
+            next(ks), cur, cfg, cfg.depth(len(cfg.channel_mult) - 1)),
         "res2": init_resblock(next(ks), cur, cur, cfg.time_dim, cfg.groups),
     }
 
@@ -267,7 +348,8 @@ def init_unet(key, cfg: UNetConfig) -> dict:
             blk = {"res": init_resblock(next(ks), cur + skip, out_ch,
                                         cfg.time_dim, cfg.groups)}
             if lvl in cfg.attention_levels:
-                blk["attn"] = init_spatial_transformer(next(ks), out_ch, cfg)
+                blk["attn"] = init_spatial_transformer(next(ks), out_ch, cfg,
+                                                       cfg.depth(lvl))
             if i == cfg.num_res_blocks and lvl != 0:
                 blk["up"] = init_conv(next(ks), out_ch, out_ch)
             ups.append(blk)
@@ -275,15 +357,40 @@ def init_unet(key, cfg: UNetConfig) -> dict:
     p["ups"] = ups
     p["norm_out"] = init_groupnorm(cur)
     p["conv_out"] = init_conv(next(ks), cur, cfg.out_channels)
+    if cfg.addition_time_embed_dim:
+        p["add1"] = init_linear(next(ks),
+                                cfg.projection_class_embeddings_input_dim,
+                                cfg.time_dim, role="time_embed", bias=True)
+        p["add2"] = init_linear(next(ks), cfg.time_dim, cfg.time_dim,
+                                role="time_embed", bias=True)
     return p
 
 
+def image_size_ids(b: int, side: int) -> jax.Array:
+    """SDXL's six size ids of a ``side`` x ``side`` image generated
+    whole: original size, crop corner (0, 0), target size."""
+    ids = jnp.asarray([side, side, 0, 0, side, side], jnp.float32)
+    return jnp.broadcast_to(ids, (b, 6))
+
+
 def apply_unet(p: dict, cfg: UNetConfig, x: jax.Array, t: jax.Array,
-               ctx: jax.Array) -> jax.Array:
-    """x: (B, H, W, 4) latent; t: (B,) timestep; ctx: (B, 77, ctx_dim)."""
+               ctx: jax.Array, text_embeds: jax.Array | None = None,
+               time_ids: jax.Array | None = None) -> jax.Array:
+    """x: (B, H, W, 4) latent; t: (B,) timestep; ctx: (B, 77, ctx_dim).
+    With the added embedding (SDXL): text_embeds (B, pooled width), the
+    pooled text state, and time_ids (B, 6), :func:`image_size_ids`."""
     temb = timestep_embedding(t, cfg.model_channels).astype(x.dtype)
     temb = apply_linear(p["time2"],
                         jax.nn.silu(apply_linear(p["time1"], temb)))
+    if cfg.addition_time_embed_dim:
+        with jax.named_scope("add_embed"):
+            b = x.shape[0]
+            ids = timestep_embedding(time_ids.reshape(-1),
+                                     cfg.addition_time_embed_dim)
+            y = jnp.concatenate([text_embeds.astype(jnp.float32),
+                                 ids.reshape(b, -1)], -1).astype(x.dtype)
+            temb = temb + apply_linear(
+                p["add2"], jax.nn.silu(apply_linear(p["add1"], y)))
     h = apply_conv(p["conv_in"], x)
     skips = [h]
     for blk in p["downs"]:

@@ -1,7 +1,10 @@
 """SD VAE decoder (latent -> image).
 
 Its convolutions are ``unet.apply_conv``'s: native convolutions on the
-device, counted as GGML's im2col ``mul_mat``."""
+device, counted as GGML's im2col ``mul_mat``.  :func:`decode` runs a
+batch in chunks of at most :data:`PIXELS_PER_CALL` output pixels, so a
+1024x1024 batch holds what SD v1.5's batch of four 512x512 images
+holds."""
 from __future__ import annotations
 
 import dataclasses
@@ -27,6 +30,10 @@ class VAEConfig:
 
 
 SD15_VAE = VAEConfig()
+SDXL_VAE = VAEConfig(scale_factor=0.13025)
+
+# Output pixels one decode call holds: SD v1.5's batch of 4 at 512x512.
+PIXELS_PER_CALL = 4 * 512 * 512
 TINY_VAE = VAEConfig(base=32, channel_mult=(1, 2), num_res_blocks=1,
                      groups=8)
 
@@ -102,3 +109,26 @@ def apply_vae_decoder(p: dict, cfg: VAEConfig, z: jax.Array) -> jax.Array:
             h = apply_conv(blk["up"], h)
     h = jax.nn.silu(groupnorm(p["norm_out"], h, cfg.groups))
     return jnp.tanh(apply_conv(p["conv_out"], h))
+
+
+def images_per_call(cfg: VAEConfig, batch: int, latent_hw: int) -> int:
+    """Images :func:`decode` decodes per call: the most of ``batch``
+    within :data:`PIXELS_PER_CALL` (at least one) that divides it."""
+    side = latent_hw * 2 ** (len(cfg.channel_mult) - 1)
+    n = max(1, min(batch, PIXELS_PER_CALL // (side * side)))
+    while batch % n:
+        n -= 1
+    return n
+
+
+def decode(p: dict, cfg: VAEConfig, z: jax.Array) -> jax.Array:
+    """:func:`apply_vae_decoder` over ``z`` in chunks of
+    :func:`images_per_call` images, one after another (``lax.map``); a
+    batch within the budget is one call."""
+    b = z.shape[0]
+    n = images_per_call(cfg, b, z.shape[1])
+    if n == b:
+        return apply_vae_decoder(p, cfg, z)
+    out = jax.lax.map(lambda c: apply_vae_decoder(p, cfg, c),
+                      z.reshape(b // n, n, *z.shape[1:]))
+    return out.reshape(b, *out.shape[2:])

@@ -15,7 +15,7 @@ from repro.diffusion import schedule as sched_mod
 from repro.engine import (TINY_SD, DiffusionEngine, GenerateRequest,
                           init_pipeline)
 from repro.engine import samplers as samplers_mod
-from repro.engine.diffusion_engine import build_denoise
+from repro.engine.diffusion_engine import TINY_XL, build_denoise
 from repro.obs import Telemetry, TraceRecorder, span
 
 
@@ -69,6 +69,28 @@ def test_lowered_program_carries_model_scopes():
     assert any("unet/conv/" in p for p in paths)
     assert any("vae/conv/" in p for p in paths)
     assert any(p.startswith("jit(fn)/clip/") for p in paths)
+
+
+@pytest.mark.parametrize("cfg,inner", [
+    (TINY_SD, {"unet/xfmr/"}),
+    (TINY_XL, {"unet/xfmr/", "clip/clip_g/", "unet/add_embed/"})])
+def test_lowered_program_carries_block_scopes(cfg, inner):
+    """Every spatial transformer runs under ``xfmr`` inside ``unet``;
+    SDXL's second encoder under ``clip_g`` inside ``clip``, its added
+    embedding under ``add_embed`` inside ``unet``."""
+    params = jax.eval_shape(lambda: init_pipeline(jax.random.PRNGKey(0),
+                                                  cfg))
+    b, tl, hw = 2, cfg.text_len, cfg.latent_hw
+    plan = samplers_mod.get_sampler("euler").plan(
+        sched_mod.NoiseSchedule(), 1, 1)
+    spec = jax.ShapeDtypeStruct
+    text = jax.jit(build_denoise(cfg, "euler", False)).lower(
+        params, spec((b, tl), jnp.int32), spec((b, tl), jnp.int32),
+        spec((b,), jnp.float32), spec((b, hw, hw, 4), jnp.float32),
+        plan).as_text(debug_info=True)
+    paths = set(re.findall(r'loc\("([^"]*/[^"]*)"', text))
+    for part in inner:
+        assert any(part in p for p in paths), part
 
 
 def test_span_records_nothing_without_a_session():
@@ -128,7 +150,8 @@ def test_engine_spans_on_the_profiler_clock(sd_params, tmp_path):
         assert a[2] <= b[1]                       # children in order
     launch = spans[5][3]
     assert launch == {"rids": "10 11", "rows": 2, "bucket": 4, "steps": 3,
-                      "sampler": "euler", "cfg": 1}
+                      "sampler": "euler", "cfg": 1, "latent_hw": 8,
+                      "vae_chunk": 2}
     assert spans[4][3] == {"rows": 2} and spans[6][3] == {"rows": 2}
 
 
