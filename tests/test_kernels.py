@@ -10,6 +10,7 @@ import pytest
 
 from repro.core import quant
 from repro.kernels import ops, ref
+from repro.kernels import q8_matmul as q8
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.q3k_matmul import q3k_matmul
 from repro.kernels.q4_matmul import q4_matmul
@@ -29,9 +30,18 @@ def _xw(m, k, n, seed=0, dtype=jnp.float32):
 ODD_K = [(16, 640, 128), (8, 768, 64), (8, 1280, 136)]
 
 
+# Shapes whose ``q8_plan`` tiles differ from a single step: M not a
+# multiple of its tile (77, 308, 600), N a whole dim not divisible by
+# 128 (320, 640), K whole (640, 1280) or split into blocks (2048, 5120),
+# and several M tiles reusing one dequantized weight panel (308, 600,
+# 1200), with the K split on top (600, 1200).
+PLANNED = [(77, 640, 320), (308, 1280, 640), (600, 2048, 640),
+           (308, 5120, 1280), (1200, 5120, 256)]
+
+
 @pytest.mark.parametrize("m,k,n", [(8, 64, 16), (32, 256, 64),
                                    (128, 1024, 256), (17, 512, 96)]
-                         + ODD_K)
+                         + ODD_K + PLANNED)
 def test_q8_dequant_kernel_matches_oracle(m, k, n):
     x, w = _xw(m, k, n, seed=m)
     wq = quant.quantize_q8_0(w)
@@ -59,6 +69,112 @@ def test_k_block_divides_k(k, pref, align, want):
     bk = k_block(k, pref, align)
     assert bk == want and k % bk == 0
     assert bk == k or (bk % align == 0 and bk <= pref)
+
+
+# The Q8_0 sites of one UNet evaluation at batch 4, (m, n, k): count
+# (``bench/configs/*_cost.matmul_calls``), with the grid steps of the old
+# (M/128, N/128, K/512-or-256) tiling.
+SDXL_UNET_Q8 = {(308, 640, 2048): 20, (308, 1280, 2048): 120,
+                (4096, 1280, 1280): 372, (4096, 1280, 5120): 60,
+                (4096, 10240, 1280): 60, (16384, 640, 640): 70,
+                (16384, 640, 2560): 10, (16384, 5120, 640): 10}
+SD15_UNET_Q8 = {(256, 1280, 1280): 6, (256, 1280, 5120): 1,
+                (256, 10240, 1280): 1, (308, 320, 768): 10,
+                (308, 640, 768): 10, (308, 1280, 768): 12,
+                (1024, 1280, 1280): 30, (1024, 1280, 5120): 5,
+                (1024, 10240, 1280): 5, (4096, 640, 640): 30,
+                (4096, 640, 2560): 5, (4096, 5120, 640): 5,
+                (16384, 320, 320): 30, (16384, 320, 1280): 5,
+                (16384, 2560, 320): 5}
+# CLIP (both SDXL encoders, the pooled projection) and VAE sites.
+OTHER_Q8 = [(4, 1280, 1280), (308, 768, 768), (308, 768, 3072),
+            (308, 3072, 768), (308, 1280, 1280), (308, 1280, 5120),
+            (308, 5120, 1280), (16384, 512, 512), (16384, 1536, 512)]
+# Decode- and prefill-sized LM projections (8B-class widths).
+LM_Q8 = [(m, n, k) for m in (1, 8, 32, 64)
+         for n, k in ((4096, 4096), (14336, 4096), (4096, 14336))]
+
+
+def _q8_steps(m, n, k, bm, bn, bk):
+    return -(-m // bm) * -(-n // bn) * (k // bk)
+
+
+@pytest.mark.parametrize("sites,old", [(SDXL_UNET_Q8, 1_698_800),
+                                       (SD15_UNET_Q8, 84_520)],
+                         ids=["sdxl", "sd15"])
+def test_q8_plan_steps_per_unet_evaluation(sites, old):
+    """The planner's engagement count: grid steps per UNet evaluation
+    fall to under 2 % of the old tiling's."""
+    def steps(plan):
+        return sum(_q8_steps(*s, *plan(*s)) * c for s, c in sites.items())
+
+    assert steps(lambda m, n, k: (min(128, m), min(128, n),
+                                  k_block(k, 512, q8.K_ALIGN))) == old
+    assert steps(q8.q8_plan) <= old // 50
+
+
+@pytest.mark.parametrize("m,n,k", sorted({*SDXL_UNET_Q8, *SD15_UNET_Q8,
+                                          *OTHER_Q8, *LM_Q8}))
+def test_q8_plan_fits_vmem_with_legal_tiles(m, n, k):
+    bm, bn, bk = q8.q8_plan(m, n, k)
+    assert q8.q8_vmem_bytes(bm, bn, bk, k) <= q8.VMEM_BUDGET
+    assert bm == m or (bm % 16 == 0 and bm <= q8.BM_MAX)
+    assert bn == n or bn % 128 == 0
+    assert k % bk == 0 and (bk == k or bk % q8.K_ALIGN == 0)
+
+
+@pytest.mark.parametrize("m,n,k", LM_Q8)
+def test_q8_plan_keeps_decode_in_one_m_tile(m, n, k):
+    assert q8.q8_plan(m, n, k)[0] == m
+
+
+def _pallas_grid(fn, *args):
+    """The grid and the block index maps of ``fn``'s one pallas_call."""
+    jx = jax.make_jaxpr(functools.partial(fn, interpret=True))(*args)
+    gm, = [e.params["grid_mapping"] for e in jx.jaxpr.eqns
+           if e.primitive.name == "pallas_call"]
+    return gm.grid, [functools.partial(jax.core.eval_jaxpr,
+                                       b.index_map_jaxpr.jaxpr,
+                                       b.index_map_jaxpr.consts)
+                     for b in gm.block_mappings]
+
+
+@pytest.mark.parametrize("m,k,n", PLANNED + [(16384, 640, 640)])
+def test_q8_weight_blocks_are_copied_once(m, k, n):
+    """The grid follows ``q8_plan`` and, walked in order, changes the
+    weight and scale block indices once per (N tile, K tile): the
+    pipeline copies the packed weights from HBM once per call."""
+    S = jax.ShapeDtypeStruct
+    grid, maps = _pallas_grid(
+        q8_matmul, S((m, k), jnp.bfloat16), S((n, k), jnp.int8),
+        S((n, k // 32), jnp.float32))
+    bm, bn, bk = q8.q8_plan(m, n, k)
+    assert grid == (-(-n // bn), -(-m // bm), k // bk)
+    for idx in maps[1:3]:
+        seen = [tuple(int(v) for v in idx(*p))
+                for p in np.ndindex(*grid)]
+        fetches = 1 + sum(a != b for a, b in zip(seen, seen[1:]))
+        assert fetches == grid[0] * grid[2]
+
+
+@pytest.mark.parametrize("kernel,grid", [
+    ("q4_0", (2, 3, 5)), ("q3_k", (2, 3, 2)), ("w8a8", (2, 3, 5))])
+def test_other_quantized_kernels_keep_their_tiles(kernel, grid):
+    """The planner is the Q8_0 dequant kernel's alone: the Q4_0, Q3_K
+    and w8a8 kernels keep 128 x 128 tiles and ``k_block``'s K tile."""
+    S = jax.ShapeDtypeStruct
+    bf, i8, u8, f32 = jnp.bfloat16, jnp.int8, jnp.uint8, jnp.float32
+    m, k, n = 256, 2048 if kernel == "q3_k" else 1280, 320
+    fn, args = {
+        "q4_0": (q4_matmul, (S((m, k), bf), S((n, k // 2), u8),
+                             S((n, k // 32), f32))),
+        "q3_k": (q3k_matmul, (S((m, k), bf), S((n, k // 4), u8),
+                              S((n, k // 8), u8), S((n, k // 16), u8),
+                              S((n, k // 256), f32))),
+        "w8a8": (q8_matmul_w8a8, (S((m, k), i8), S((m, k // 32), f32),
+                                  S((n, k), i8), S((n, k // 32), f32))),
+    }[kernel]
+    assert _pallas_grid(fn, *args)[0] == grid
 
 
 @pytest.mark.parametrize("m,k,n", [(8, 64, 16), (64, 512, 128),

@@ -69,10 +69,17 @@ def _matmul(x, w):
 
 # (K, N) of SD-Turbo's Q8_0 sites: level-0 attention (320), cross-
 # attention K/V from the 768-wide context, level-2 attention (1280) and
-# the level-2 feed-forward down projection (5120 -> 1280).
-@pytest.mark.parametrize("k,n", [(320, 320), (768, 320), (1280, 1280),
-                                 (5120, 1280)])
-@pytest.mark.parametrize("m", [77, 4096])
+# the level-2 feed-forward down projection (5120 -> 1280), at M = 77 and
+# 4096.  Then SDXL's sites at batch 4, whose ``q8_plan`` tiles are the
+# largest: the 1280-wide level's feed-forward up projection, the
+# 640-wide level's up and down projections at M = 16384, and cross-
+# attention K/V from the 2048-wide context; and SD v1.5's level-0
+# feed-forward up projection.  A plan that overflows VMEM fails here.
+@pytest.mark.parametrize("m,k,n", [
+    (m, k, n) for m in (77, 4096)
+    for k, n in ((320, 320), (768, 320), (1280, 1280), (5120, 1280))] + [
+    (4096, 1280, 10240), (16384, 640, 5120), (16384, 2560, 640),
+    (308, 2048, 1280), (16384, 320, 2560)])
 def test_q8_matmul_compiles(one_chip, m, k, n):
     text = _compiled_text(_matmul, _spec(one_chip, (m, k), jnp.bfloat16),
                           _weight(one_chip, "q8_0", n, k))
